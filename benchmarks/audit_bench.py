@@ -31,6 +31,7 @@ def measure(*, check: bool = True) -> dict:
     if check and os.path.exists(BASELINE):
         cmd += ["--check", BASELINE]
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # a CPU analysis; never reach for a chip
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(ROOT, "src"), env.get("PYTHONPATH", "")]
     ).rstrip(os.pathsep)
@@ -46,6 +47,7 @@ def measure(*, check: bool = True) -> dict:
     with open(out_path) as fh:
         report = json.load(fh)
     return {
+        "platform": "cpu",
         "wall_s": round(wall, 3),
         "wall_budget_s": WALL_BUDGET_S,
         "within_budget": wall <= WALL_BUDGET_S,

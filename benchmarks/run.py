@@ -9,7 +9,7 @@ no argument runs everything.
   tc       -> §III/IV: compacted cover-edge pipeline vs the dense seed
               path vs wedge-iterator; also writes ``results/BENCH_tc.json``
               so the perf trajectory is tracked across PRs
-  parallel -> Alg. 2 end-to-end on p = 8 simulated devices (subprocess):
+  parallel -> Alg. 2 end-to-end on p = 8 simulated CPU devices (subprocess):
               allgather vs ring wall time, per-round estimate, planned
               bucket occupancy, wedge-baseline agreement; writes
               ``results/BENCH_parallel.json``
@@ -32,8 +32,8 @@ no argument runs everything.
               the scale-10 fixture (must stay < 5%); writes
               ``results/BENCH_api.json``
   comm     -> measured vs modeled communication per phase for
-              p in {1, 2, 4, 8} on scale-10/12 RMAT (subprocess, 8 host
-              devices) + the k·m·p hedge-volume scaling curve; writes
+              p in {1, 2, 4, 8} on scale-10/12 RMAT (subprocess, 8 simulated
+              CPU devices) + the k·m·p hedge-volume scaling curve; writes
               ``results/BENCH_comm.json``.  ``comm_smoke`` is the CI
               variant (scale 10, p = 4 only; writes the untracked
               ``results/BENCH_comm_smoke.json``)
@@ -142,9 +142,14 @@ def _run_in_8dev_subprocess(body: str, json_out: str, tag: str) -> None:
     the first jax import, hence the subprocess) and report its output.
     A failing subprocess fails THIS process too — these benches gate CI
     (the comm smoke's measured==tally asserts), so an error must turn
-    the step red, not print a CSV line and exit 0."""
+    the step red, not print a CSV line and exit 0.
+
+    The child is a CPU simulation by design: ``JAX_PLATFORMS=cpu`` keeps
+    it off the accelerator (which this process may already hold), and
+    its CSV lines are labelled ``platform=cpu``."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(_ROOT, "src")
     env["PYTHONPATH"] = (
         src + os.pathsep + _ROOT + os.pathsep + env.get("PYTHONPATH", "")
@@ -155,8 +160,9 @@ def _run_in_8dev_subprocess(body: str, json_out: str, tag: str) -> None:
         err = out.stderr.strip().splitlines()[-1][:200] if out.stderr else "?"
         print(f"{tag},0,ERROR:{err}")
         raise SystemExit(f"{tag} bench subprocess failed: {err}")
-    print(out.stdout.strip())
-    print(f"{tag}_json,0,written={json_out}")
+    for line in out.stdout.strip().splitlines():
+        print(f"{line}|platform=cpu" if line.count(",") >= 2 else line)
+    print(f"{tag}_json,0,written={json_out}|platform=cpu")
 
 
 def bench_comm(smoke: bool = False):
@@ -295,7 +301,7 @@ def bench_audit():
         fh.write("\n")
     print(f"audit,{res['wall_s'] * 1e6:.0f},findings={res['findings']}"
           f"|baseline_checked={res['baseline_checked']}"
-          f"|within_budget={res['within_budget']}")
+          f"|within_budget={res['within_budget']}|platform=cpu")
     assert res["within_budget"], (
         f"static audit took {res['wall_s']}s > {res['wall_budget_s']}s "
         f"budget — it must stay cheap enough to gate every PR"
@@ -328,6 +334,9 @@ def main(argv: list[str] | None = None) -> None:
     unknown = [a for a in argv if a not in BENCHES]
     if unknown:
         sys.exit(f"unknown bench(es) {unknown}; choose from {list(BENCHES)}")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     # run-everything excludes the smoke lanes: they are strict CI
     # subsets of comm/robust (and write separate *_smoke.json files)

@@ -48,7 +48,7 @@ def jnp_index_dtype(bound: int, *, site: str) -> np.dtype:
     Under default x32 mode jax silently *downcasts* int64 arrays to
     int32 — the exact silent wrap this policy exists to prevent — so a
     bound that needs int64 raises :class:`IndexWidthError` naming the
-    call site unless x64 is enabled (``jax.experimental.enable_x64()``
+    call site unless x64 is enabled (``jax.enable_x64(True)``
     or the ``jax_enable_x64`` config flag)."""
     dt = index_dtype(bound)
     if dt == np.dtype(np.int64):

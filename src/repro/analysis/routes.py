@@ -239,7 +239,6 @@ class RouteSpec:
         ``p`` local devices (CI forces 8 host devices via XLA_FLAGS)."""
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from repro.compat import shard_map
         from repro.core.parallel_tc import (
             build_tc_shard_fn,
             result_out_specs,
@@ -258,7 +257,7 @@ class RouteSpec:
             intersect_backend=self.backend, interpret=self.interpret,
             per_vertex=self.per_vertex,
         )
-        shard = shard_map(
+        shard = jax.shard_map(
             fn, mesh=mesh, in_specs=(P("p"), P("p")),
             out_specs=result_out_specs("p", per_vertex=self.per_vertex),
         )

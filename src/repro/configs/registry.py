@@ -17,8 +17,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.configs.lm import LM_SHAPES, LONG_CONTEXT_OK
 from repro.distributed import sharding as sh
 from repro.launch import steps
@@ -356,7 +354,7 @@ def _tc_cell(cfg: dict, shape_name: str, mesh: Mesh) -> Cell:
         slack=info.get("slack", 4.0),
         frontier_dtype=info.get("frontier_dtype", "int32"),
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         fn_shard, mesh=tc_mesh, in_specs=(P("p"), P("p")),
         out_specs=result_out_specs("p"),
     )

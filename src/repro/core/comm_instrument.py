@@ -13,7 +13,7 @@
      collective (kind, per-shard shape, enclosing-loop multiplier),
      pricing each with the ``comm_model.*_wire_bytes`` conventions;
      ``verify_against_hlo`` cross-checks the inventory against the
-     StableHLO text (``compat.cost_analysis`` offers the XLA-side
+     StableHLO text (``Compiled.cost_analysis()`` offers the XLA-side
      aggregate for context);
   3. **closed-form model** — ``comm_model.wire_bytes_report``, keyed by
      the same ``WIRE_PHASES`` names.
@@ -311,7 +311,6 @@ def measure_tc_comm(
     """
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core.parallel_tc import build_tc_shard_fn, result_out_specs
 
     if mesh is None:
@@ -327,7 +326,7 @@ def measure_tc_comm(
         mode=mode, hedge_chunk=hedge_chunk, frontier_dtype=frontier_dtype,
         hplan=hplan, per_vertex=per_vertex,
     )
-    shard = shard_map(
+    shard = jax.shard_map(
         fn, mesh=mesh, in_specs=(P(axis_name), P(axis_name)),
         out_specs=result_out_specs(axis_name, per_vertex=per_vertex),
     )

@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import pvary, shard_map
 from repro.core.bfs import UNVISITED, bfs_levels
 from repro.core.comm_instrument import CommTally, tally_comm
 from repro.core.edges import horizontal_mask, mindeg_exceedance
@@ -266,8 +265,8 @@ def _tc_shard(
     )
 
     # fori_loop carries must be device-varying from the start (shard_map vma)
-    t0 = pvary(jnp.int32(0), (axis_name,))
-    o0 = pvary(jnp.bool_(False), (axis_name,))
+    t0 = jax.lax.pvary(jnp.int32(0), (axis_name,))
+    o0 = jax.lax.pvary(jnp.bool_(False), (axis_name,))
     if mode == "allgather":
         # one collective, volume k·m·p — identical to the paper's p rounds
         all_hv = jax.lax.all_gather(hv, axis_name).reshape(-1)
@@ -429,7 +428,7 @@ def _parallel_triangle_count(
         intersect_backend=backend, interpret=interpret,
         frontier_dtype=frontier_dtype, per_vertex=bool(o.per_vertex),
     )
-    shard = shard_map(
+    shard = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name)),

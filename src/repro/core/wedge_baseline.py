@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.intersect import edge_exists
 from repro.core.sampling import repartition_by_value
 from repro.graph.csr import Graph
@@ -137,7 +136,7 @@ def parallel_wedge_triangle_count(
         _wedge_shard, n=g.n_nodes, p=p, d_pad=d_pad, cap_chunk=cap_chunk,
         axis_name=axis_name,
     )
-    shard = shard_map(
+    shard = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P()),

@@ -12,8 +12,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh
-
 
 def _clean_axis(ax, names):
     if ax is None:
@@ -25,8 +23,7 @@ def _clean_axis(ax, names):
 
 
 def maybe_constrain(x, *spec_axes):
-    mesh = get_abstract_mesh()
-    names = getattr(mesh, "axis_names", ())
+    names = jax.sharding.get_abstract_mesh().axis_names
     if not names:
         return x
     cleaned = P(*(_clean_axis(a, names) for a in spec_axes))

@@ -24,6 +24,12 @@ never pay hub padding on the candidate side.
 
 Grid: (Q/BQ, Dc/BD, Dt/BD); the counter outputs are revisited across the
 inner two grid dims and accumulated in place (sequential TPU grid).
+
+TPU layout: every block is 2-D.  Per-query values (the apex level
+``lev_u`` in, the counters out) travel as ``(Q, 1)`` columns with
+``(BQ, 1)`` blocks — the Mosaic lowering refuses rank-1 blocks narrower
+than a 128-lane tile.  Outputs carry the mesh axes their inputs vary
+over, so the kernels trace inside ``jax.shard_map`` with ``check_vma``.
 """
 from __future__ import annotations
 
@@ -32,6 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 CAND_PAD = -1
 TARG_PAD = -2
@@ -47,19 +54,14 @@ def _resolve_interpret(interpret):
     return default_interpret() if interpret is None else bool(interpret)
 
 
-def _kernel(cand_ref, targ_ref, lev_c_ref, lev_u_ref, c1_ref, c2_ref):
-    i1 = pl.program_id(1)
-    i2 = pl.program_id(2)
-
-    @pl.when((i1 == 0) & (i2 == 0))
-    def _init():
-        c1_ref[...] = jnp.zeros_like(c1_ref)
-        c2_ref[...] = jnp.zeros_like(c2_ref)
-
-    cand = cand_ref[...]  # (BQ, BD) int32, sorted rows, pad -1
-    targ = targ_ref[...]  # (BQ, BD) int32, sorted rows, pad -2
-    # tile-level early out: sorted rows => ranges that don't overlap anywhere
-    # in the whole tile can never match (pads are negative, real ids >= 0)
+def _tile_hit(cand_ref, targ_ref, body):
+    """Run ``body(hit)`` on this grid step's tile pair unless their value
+    ranges cannot overlap.  ``hit`` is bool[BQ, BD]: which candidates
+    (sorted rows, pad -1) occur in the target row (pad -2)."""
+    cand = cand_ref[...]
+    targ = targ_ref[...]
+    # sorted rows => ranges that don't overlap anywhere in the whole tile
+    # can never match (pads are negative, real ids >= 0)
     c_lo, c_hi = jnp.min(cand), jnp.max(cand)
     t_lo, t_hi = jnp.min(targ), jnp.max(targ)
     overlap = (c_hi >= 0) & (t_hi >= 0) & (c_lo <= t_hi) & (t_lo <= c_hi)
@@ -67,10 +69,101 @@ def _kernel(cand_ref, targ_ref, lev_c_ref, lev_u_ref, c1_ref, c2_ref):
     @pl.when(overlap)
     def _work():
         eq = cand[:, :, None] == targ[:, None, :]
-        hit = jnp.any(eq, axis=2) & (cand >= 0)
-        same = lev_c_ref[...] == lev_u_ref[...][:, None]
-        c1_ref[...] += jnp.sum(hit & ~same, axis=1).astype(jnp.int32)
-        c2_ref[...] += jnp.sum(hit & same, axis=1).astype(jnp.int32)
+        body(jnp.any(eq, axis=2) & (cand >= 0))
+
+
+def _row_sum(mask):
+    return jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
+
+
+def _kernel(cand_ref, targ_ref, lev_c_ref, lev_u_ref, c1_ref, c2_ref):
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _init():
+        c1_ref[...] = jnp.zeros_like(c1_ref)
+        c2_ref[...] = jnp.zeros_like(c2_ref)
+
+    def body(hit):
+        same = lev_c_ref[...] == lev_u_ref[...]  # (BQ, BD) vs (BQ, 1)
+        c1_ref[...] += _row_sum(hit & ~same)
+        c2_ref[...] += _row_sum(hit & same)
+
+    _tile_hit(cand_ref, targ_ref, body)
+
+
+def _count_kernel(cand_ref, targ_ref, cnt_ref):
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _init():
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+    def body(hit):
+        cnt_ref[...] += _row_sum(hit)
+
+    _tile_hit(cand_ref, targ_ref, body)
+
+
+def _hits_kernel(cand_ref, targ_ref, hit_ref):
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        hit_ref[...] = jnp.zeros_like(hit_ref)
+
+    def body(hit):
+        hit_ref[...] |= hit.astype(jnp.int32)
+
+    _tile_hit(cand_ref, targ_ref, body)
+
+
+def _launch(kernel, cand, targ, extra, out_cols, *, block_q, block_d,
+            interpret):
+    """Pad ``cand``/``targ`` (and the per-candidate / per-query ``extra``
+    inputs) up to block multiples and run ``kernel`` over the
+    (Q/BQ, Dc/BD, Dt/BD) grid.  ``out_cols`` lists each int32 output's
+    column layout: ``1`` for a per-query ``(Q, 1)`` counter, ``"cand"``
+    for a ``(Q, Dc)`` per-candidate tile.  Returns the padded outputs."""
+    q, dc = cand.shape
+    dt = targ.shape[1]
+    qp = -(-q // block_q) * block_q
+    dcp = -(-dc // block_d) * block_d
+    dtp = -(-dt // block_d) * block_d
+    cand = jnp.pad(cand, ((0, qp - q), (0, dcp - dc)), constant_values=CAND_PAD)
+    targ = jnp.pad(targ, ((0, qp - q), (0, dtp - dt)), constant_values=TARG_PAD)
+    cand_spec = pl.BlockSpec((block_q, block_d), lambda iq, i1, i2: (iq, i1))
+    row_spec = pl.BlockSpec((block_q, 1), lambda iq, i1, i2: (iq, 0))
+    in_specs = [
+        cand_spec,
+        pl.BlockSpec((block_q, block_d), lambda iq, i1, i2: (iq, i2)),
+    ]
+    args = [cand, targ]
+    for x, pad in extra:
+        if x.ndim == 2:  # per-candidate, laid out like cand
+            x = jnp.pad(x, ((0, qp - q), (0, dcp - dc)), constant_values=pad)
+            in_specs.append(cand_spec)
+        else:  # per-query column
+            x = jnp.pad(x, (0, qp - q), constant_values=pad)[:, None]
+            in_specs.append(row_spec)
+        args.append(x)
+    vma = frozenset().union(*(jax.typeof(x).vma for x in args))
+    interpret = _resolve_interpret(interpret)
+    if interpret and vma:
+        # inside shard_map the HLO interpreter evaluates the kernel on
+        # device-varying blocks without tracking their mesh axes, so
+        # check_vma rejects the kernel's own constants; the TPU
+        # interpreter simulates each device and tracks them
+        interpret = pltpu.InterpretParams()
+    out_specs, out_shape = [], []
+    for cols in out_cols:
+        wide = cols == "cand"
+        out_specs.append(cand_spec if wide else row_spec)
+        out_shape.append(jax.ShapeDtypeStruct(
+            (qp, dcp if wide else 1), jnp.int32, vma=vma
+        ))
+    return pl.pallas_call(
+        kernel,
+        grid=(qp // block_q, dcp // block_d, dtp // block_d),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(*args)
 
 
 @functools.partial(
@@ -88,58 +181,12 @@ def intersect_pallas(
 ):
     """See ref.intersect_ref. Shapes are padded up to block multiples here;
     ``cand`` and ``targ`` may have different widths."""
-    interpret = _resolve_interpret(interpret)
-    q, dc = cand.shape
-    dt = targ.shape[1]
-    qp = -(-q // block_q) * block_q
-    dcp = -(-dc // block_d) * block_d
-    dtp = -(-dt // block_d) * block_d
-    cand = jnp.pad(cand, ((0, qp - q), (0, dcp - dc)), constant_values=CAND_PAD)
-    targ = jnp.pad(targ, ((0, qp - q), (0, dtp - dt)), constant_values=TARG_PAD)
-    lev_c = jnp.pad(lev_c, ((0, qp - q), (0, dcp - dc)), constant_values=-7)
-    lev_u = jnp.pad(lev_u, (0, qp - q), constant_values=-9)
-    grid = (qp // block_q, dcp // block_d, dtp // block_d)
-    c1, c2 = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q, block_d), lambda iq, i1, i2: (iq, i1)),
-            pl.BlockSpec((block_q, block_d), lambda iq, i1, i2: (iq, i2)),
-            pl.BlockSpec((block_q, block_d), lambda iq, i1, i2: (iq, i1)),
-            pl.BlockSpec((block_q,), lambda iq, i1, i2: (iq,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_q,), lambda iq, i1, i2: (iq,)),
-            pl.BlockSpec((block_q,), lambda iq, i1, i2: (iq,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qp,), jnp.int32),
-            jax.ShapeDtypeStruct((qp,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(cand, targ, lev_c, lev_u)
-    return c1[:q], c2[:q]
-
-
-def _count_kernel(cand_ref, targ_ref, cnt_ref):
-    i1 = pl.program_id(1)
-    i2 = pl.program_id(2)
-
-    @pl.when((i1 == 0) & (i2 == 0))
-    def _init():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-    cand = cand_ref[...]
-    targ = targ_ref[...]
-    c_lo, c_hi = jnp.min(cand), jnp.max(cand)
-    t_lo, t_hi = jnp.min(targ), jnp.max(targ)
-    overlap = (c_hi >= 0) & (t_hi >= 0) & (c_lo <= t_hi) & (t_lo <= c_hi)
-
-    @pl.when(overlap)
-    def _work():
-        eq = cand[:, :, None] == targ[:, None, :]
-        hit = jnp.any(eq, axis=2) & (cand >= 0)
-        cnt_ref[...] += jnp.sum(hit, axis=1).astype(jnp.int32)
+    q = cand.shape[0]
+    c1, c2 = _launch(
+        _kernel, cand, targ, [(lev_c, -7), (lev_u, -9)], [1, 1],
+        block_q=block_q, block_d=block_d, interpret=interpret,
+    )
+    return c1[:q, 0], c2[:q, 0]
 
 
 @functools.partial(
@@ -162,47 +209,11 @@ def intersect_pallas_count(
     must be unique (adjacency lists / transposed sublists are), so a
     candidate is counted in at most one target tile.
     """
-    interpret = _resolve_interpret(interpret)
-    q, dc = cand.shape
-    dt = targ.shape[1]
-    qp = -(-q // block_q) * block_q
-    dcp = -(-dc // block_d) * block_d
-    dtp = -(-dt // block_d) * block_d
-    cand = jnp.pad(cand, ((0, qp - q), (0, dcp - dc)), constant_values=CAND_PAD)
-    targ = jnp.pad(targ, ((0, qp - q), (0, dtp - dt)), constant_values=TARG_PAD)
-    grid = (qp // block_q, dcp // block_d, dtp // block_d)
-    cnt = pl.pallas_call(
-        _count_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q, block_d), lambda iq, i1, i2: (iq, i1)),
-            pl.BlockSpec((block_q, block_d), lambda iq, i1, i2: (iq, i2)),
-        ],
-        out_specs=pl.BlockSpec((block_q,), lambda iq, i1, i2: (iq,)),
-        out_shape=jax.ShapeDtypeStruct((qp,), jnp.int32),
-        interpret=interpret,
-    )(cand, targ)
-    return cnt[:q]
-
-
-def _hits_kernel(cand_ref, targ_ref, hit_ref):
-    i2 = pl.program_id(2)
-
-    @pl.when(i2 == 0)
-    def _init():
-        hit_ref[...] = jnp.zeros_like(hit_ref)
-
-    cand = cand_ref[...]
-    targ = targ_ref[...]
-    c_lo, c_hi = jnp.min(cand), jnp.max(cand)
-    t_lo, t_hi = jnp.min(targ), jnp.max(targ)
-    overlap = (c_hi >= 0) & (t_hi >= 0) & (c_lo <= t_hi) & (t_lo <= c_hi)
-
-    @pl.when(overlap)
-    def _work():
-        eq = cand[:, :, None] == targ[:, None, :]
-        hit = jnp.any(eq, axis=2) & (cand >= 0)
-        hit_ref[...] |= hit.astype(jnp.int32)
+    (cnt,) = _launch(
+        _count_kernel, cand, targ, [], [1],
+        block_q=block_q, block_d=block_d, interpret=interpret,
+    )
+    return cnt[: cand.shape[0], 0]
 
 
 @functools.partial(
@@ -220,24 +231,9 @@ def intersect_pallas_hits(
     which candidates appear in the target row.  Same tiling/early-out as
     ``intersect_pallas``; the (BQ, BDc) hit tile is revisited across the
     target grid dim and OR-accumulated in place."""
-    interpret = _resolve_interpret(interpret)
     q, dc = cand.shape
-    dt = targ.shape[1]
-    qp = -(-q // block_q) * block_q
-    dcp = -(-dc // block_d) * block_d
-    dtp = -(-dt // block_d) * block_d
-    cand = jnp.pad(cand, ((0, qp - q), (0, dcp - dc)), constant_values=CAND_PAD)
-    targ = jnp.pad(targ, ((0, qp - q), (0, dtp - dt)), constant_values=TARG_PAD)
-    grid = (qp // block_q, dcp // block_d, dtp // block_d)
-    hit = pl.pallas_call(
-        _hits_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q, block_d), lambda iq, i1, i2: (iq, i1)),
-            pl.BlockSpec((block_q, block_d), lambda iq, i1, i2: (iq, i2)),
-        ],
-        out_specs=pl.BlockSpec((block_q, block_d), lambda iq, i1, i2: (iq, i1)),
-        out_shape=jax.ShapeDtypeStruct((qp, dcp), jnp.int32),
-        interpret=interpret,
-    )(cand, targ)
+    (hit,) = _launch(
+        _hits_kernel, cand, targ, [], ["cand"],
+        block_q=block_q, block_d=block_d, interpret=interpret,
+    )
     return hit[:q, :dc] > 0

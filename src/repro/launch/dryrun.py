@@ -27,8 +27,6 @@ from pathlib import Path  # noqa: E402
 
 import jax  # noqa: E402
 
-from repro.compat import cost_analysis, set_mesh  # noqa: E402
-
 RESULTS = Path(__file__).resolve().parents[3] / "results"
 
 from repro.launch.hlo_analysis import collective_bytes  # noqa: E402
@@ -43,7 +41,7 @@ def run_cell(arch: str, shape: str, mesh, *, smoke: bool = False,
     if cell.skipped:
         return {"arch": arch, "shape": shape, "status": "skipped",
                 "reason": cell.skip_reason, "model_flops": 0.0}
-    with set_mesh(cell.mesh if cell.mesh is not None else mesh):
+    with jax.set_mesh(cell.mesh if cell.mesh is not None else mesh):
         jitted = jax.jit(
             cell.fn, in_shardings=cell.in_shardings,
             out_shardings=cell.out_shardings,
@@ -53,7 +51,7 @@ def run_cell(arch: str, shape: str, mesh, *, smoke: bool = False,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     rec = {
         "arch": arch,
@@ -64,13 +62,12 @@ def run_cell(arch: str, shape: str, mesh, *, smoke: bool = False,
         "hlo_flops": float(cost.get("flops", 0.0)),
         "hlo_bytes": float(cost.get("bytes accessed", 0.0)),
         "collective_bytes": coll,
-        "argument_bytes": getattr(mem, "argument_size_in_bytes", 0),
-        "output_bytes": getattr(mem, "output_size_in_bytes", 0),
-        "temp_bytes": getattr(mem, "temp_size_in_bytes", 0),
+        "argument_bytes": mem.argument_size_in_bytes,
+        "output_bytes": mem.output_size_in_bytes,
+        "temp_bytes": mem.temp_size_in_bytes,
         "peak_bytes": (
-            getattr(mem, "argument_size_in_bytes", 0)
-            + getattr(mem, "output_size_in_bytes", 0)
-            + getattr(mem, "temp_size_in_bytes", 0)
+            mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes
         ),
         "lower_s": round(t_lower, 2),
         "compile_s": round(t_compile, 2),

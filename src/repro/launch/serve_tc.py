@@ -713,12 +713,7 @@ class TriangleServer:
 
     @staticmethod
     def _batch_ready(res) -> bool:
-        try:
-            return all(
-                x.is_ready() for x in jax.tree_util.tree_leaves(res)
-            )
-        except AttributeError:  # older jax without Array.is_ready
-            return False
+        return all(x.is_ready() for x in jax.tree_util.tree_leaves(res))
 
     def _poll_inflight(self) -> None:
         """Finalize every already-finished in-flight batch NOW, so its
@@ -790,11 +785,7 @@ class TriangleServer:
         hits = ps["hits"] - self._plan_baseline[0]
         misses = ps["misses"] - self._plan_baseline[1]
         looked = hits + misses
-        jit_now = _jit_cache_size()
-        jit_compiles = (
-            max(0, jit_now - self._jit_baseline)
-            if jit_now >= 0 and self._jit_baseline >= 0 else None
-        )
+        jit_compiles = max(0, _jit_cache_size() - self._jit_baseline)
         return {
             "plan_hit": 1.0 if looked <= 0 else hits / looked,
             "jit_compiles": jit_compiles,
@@ -873,10 +864,7 @@ def lanes_ladder(batch_size: int) -> list[int]:
 
 
 def _jit_cache_size() -> int:
-    try:
-        return int(seq._tc_batch_fused._cache_size())
-    except Exception:
-        return -1
+    return int(seq._tc_batch_fused._cache_size())
 
 
 def measure_serve(
@@ -984,7 +972,7 @@ def measure_serve(
             "batches": stats["batches"],
             "speedup_vs_sequential": seq_wall / wall,
             "plan_cache_hit_rate": plan_stats["hits"] / max(looked, 1),
-            "jit_compiles_measured": max(0, jit1 - jit0) if jit0 >= 0 else None,
+            "jit_compiles_measured": max(0, jit1 - jit0),
             "triangles_total": total,
             "agree": agree,
         }
@@ -1020,6 +1008,9 @@ def main(argv: Optional[list[str]] = None) -> None:
     # full-run perf trajectory tracked across PRs (README "Benchmarks")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.out is None:
         args.out = os.path.join(
             "results",

@@ -23,8 +23,6 @@ import dataclasses
 from typing import Optional
 
 import jax
-
-from repro.compat import get_abstract_mesh
 import jax.numpy as jnp
 
 from repro.models.layers import dense_init, glu_mlp, glu_mlp_init
@@ -91,7 +89,7 @@ def moe_ffn(params, cfg: MoEConfig, x, *, capacity: Optional[int] = None):
     if cfg.dispatch == "a2a":
         from repro.models.moe_a2a import a2a_applicable, moe_ffn_a2a
 
-        mesh = get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if a2a_applicable(cfg, x, mesh):
             return moe_ffn_a2a(params, cfg, x)
     b, s, d = x.shape

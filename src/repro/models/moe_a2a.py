@@ -30,8 +30,6 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh, shard_map
-
 from repro.models.layers import glu_mlp
 
 
@@ -40,8 +38,7 @@ def _axes(mesh, names):
 
 
 def a2a_applicable(cfg, x, mesh) -> bool:
-    names = getattr(mesh, "axis_names", ())
-    if "model" not in names:
+    if "model" not in mesh.axis_names:
         return False
     n_model = mesh.shape["model"]
     return x.shape[1] % n_model == 0 and cfg.n_phys % n_model == 0
@@ -49,7 +46,7 @@ def a2a_applicable(cfg, x, mesh) -> bool:
 
 def moe_ffn_a2a(params, cfg, x):
     """x [B, S, D] (sharded (pod,data) on B) -> (out, aux)."""
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     model_ax = "model"
     data_axes = _axes(mesh, ("pod", "data"))
     n_model = mesh.shape[model_ax]
@@ -133,7 +130,7 @@ def moe_ffn_a2a(params, cfg, x):
     shared_spec = (
         jax.tree.map(lambda _: P(), shared) if shared is not None else None
     )
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), experts_spec, shared_spec,

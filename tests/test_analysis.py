@@ -226,7 +226,7 @@ class TestIndexDtypePolicy:
         assert np.dtype(g.src.dtype) == np.dtype(np.int32)
         got = jax.eval_shape(lambda gr: gr.row_offsets[-1], g)
         assert np.dtype(got.dtype) == np.dtype(np.int32)  # the hazard
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             got64 = jax.eval_shape(lambda gr: gr.row_offsets[-1], g)
         assert np.dtype(got64.dtype) == np.dtype(np.int64)
 

@@ -157,6 +157,11 @@ def test_to_batch_roundtrip_wrapper():
 
 
 def test_plan_cache_hits_and_meta_quantization():
+    from repro.core import sequential
+
+    # the cache is process-global: another test in this worker may have
+    # planned the same batch profile already
+    sequential._BATCH_PLAN_CACHE.clear()
     batch_plan_cache_stats(reset=True)
     before = batch_plan_cache_stats()["size"]
     graphs_a = [gen.erdos_renyi(50, 0.1, seed=1), gen.erdos_renyi(48, 0.1, seed=2)]

@@ -73,7 +73,6 @@ def test_moe_a2a_equals_gspmd_multidevice():
     body = """
     import jax, jax.numpy as jnp, dataclasses
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import set_mesh
     from repro.models.moe import MoEConfig, moe_ffn, moe_ffn_init
 
     mesh = jax.make_mesh((2, 4), ("data", "model"))
@@ -82,7 +81,7 @@ def test_moe_a2a_equals_gspmd_multidevice():
     cfg_a = dataclasses.replace(cfg_g, dispatch="a2a")
     params = moe_ffn_init(jax.random.key(0), cfg_g, 16)
     x = jax.random.normal(jax.random.key(1), (4, 8, 16))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
         ps = jax.device_put(params, jax.tree.map(
             lambda _: NamedSharding(mesh, P()), params))

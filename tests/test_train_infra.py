@@ -121,7 +121,6 @@ def test_int8_compressed_psum_single_device():
     """Numerical property of the quantizer on a trivial 1-device mesh."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.train.trainer import int8_compressed_psum
 
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1), ("d",))
@@ -132,7 +131,7 @@ def test_int8_compressed_psum_single_device():
         return int8_compressed_psum(tree, "d")
 
     out = jax.jit(
-        shard_map(f, mesh=mesh, in_specs=({"w": P()},),
+        jax.shard_map(f, mesh=mesh, in_specs=({"w": P()},),
                   out_specs={"w": P()}),
     )(g)
     err = float(jnp.abs(out["w"] - g["w"]).max())
