@@ -59,7 +59,6 @@ no argument runs everything.
               host-sync, collectives, dead code over every route) plus
               the baseline diff must finish within 60 s; writes
               ``results/BENCH_audit.json``
-  roofline -> §Roofline terms from the dry-run artifacts (if present)
 """
 from __future__ import annotations
 
@@ -274,23 +273,6 @@ def bench_stream(smoke: bool = False):
         measure_stream(scale=12, batches=20, out=out)
 
 
-def bench_roofline():
-    from benchmarks.roofline import RESULTS, analyze
-
-    for mesh in ("pod", "multipod"):
-        for variant, label in (("_baseline", "base"), ("_opt", "opt")):
-            path = RESULTS / f"dryrun_{mesh}{variant}.json"
-            if not path.exists():
-                continue
-            ok = [r for r in analyze(mesh, variant=variant)
-                  if r["status"] == "ok"]
-            for r in ok:
-                print(
-                    f"roofline_{mesh}_{label}_{r['cell'].replace('|','_x_')},"
-                    f"0,dom={r['dominant']}|frac={r['roofline_frac']:.2f}"
-                    f"|peakGB={r['peak_gb']:.1f}")
-
-
 def bench_audit():
     from benchmarks.audit_bench import measure
 
@@ -325,7 +307,6 @@ BENCHES = {
     "stream": bench_stream,
     "stream_smoke": lambda: bench_stream(smoke=True),
     "audit": bench_audit,
-    "roofline": bench_roofline,
 }
 
 

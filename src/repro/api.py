@@ -39,6 +39,7 @@ from typing import Optional, Sequence, Union
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core import parallel_tc as _ptc
 from repro.core import sequential as _seq
 from repro.core.approx import ApproxEstimate, wedge_sample_estimate
@@ -696,6 +697,7 @@ class TriangleEngine:
         ))
 
     # ------------------------------------------------------ public API
+    @obs.spanned("tc.count")
     def count(
         self,
         graph_or_edges: Union[Graph, EdgeList],
@@ -782,10 +784,11 @@ class TriangleEngine:
         if r == "batch":
             # pack the RAW edges once (a Graph input round-trips to the
             # host; an edge-list input never builds the intermediate CSR)
-            gb = from_edges_batch(
-                [_host_edges(g) if is_graph else (edges, n_nodes)],
-                grid=self.budgets,
-            )
+            with obs.span("tc.pack"):
+                gb = from_edges_batch(
+                    [_host_edges(g) if is_graph else (edges, n_nodes)],
+                    grid=self.budgets,
+                )
             plan = self.plan_for(gb)
             res = self.count_batch_raw(gb, options=o, plan=plan)
             res = _seq._squeeze_lane(res)
@@ -795,7 +798,8 @@ class TriangleEngine:
                                       plan_id=_plan_id(plan, "bounded"),
                                       deg=gb.deg[0], n=n_nodes)
         if g is None:
-            g = from_edges(edges, n_nodes)
+            with obs.span("tc.ingest"):
+                g = from_edges(edges, n_nodes)
         if r == "local":
             res = self.count_raw(g, options=o)
             return self._report_local(res, o, route="local", plan_id=None,
@@ -829,10 +833,11 @@ class TriangleEngine:
             gb, n_real = graphs, graphs.batch_size
         else:
             graphs = list(graphs)
-            gb = from_edges_batch(
-                [(np.asarray(e), int(n)) for e, n in graphs],
-                grid=self.budgets,
-            )
+            with obs.span("tc.pack"):
+                gb = from_edges_batch(
+                    [(np.asarray(e), int(n)) for e, n in graphs],
+                    grid=self.budgets,
+                )
             n_real = len(graphs)
         plan = None
         can_plan = (gb.meta is not None and o.d_max is None
@@ -982,10 +987,11 @@ class TriangleEngine:
         deg=None,
         n: Optional[int] = None,
     ) -> TriangleReport:
-        tri, c1, c2, nh, k, ovf, lev = jax.device_get(
-            (res.triangles, res.c1, res.c2, res.num_horizontal, res.k,
-             res.h_overflow, res.levels)
-        )
+        with obs.span("tc.fetch"):
+            tri, c1, c2, nh, k, ovf, lev = jax.device_get(
+                (res.triangles, res.c1, res.c2, res.num_horizontal, res.k,
+                 res.h_overflow, res.levels)
+            )
         backend, _ = resolve_backend(o.backend, o.interpret)
         plan_id = plan_id or f"exact/{backend}"
         pv = degs = None

@@ -168,7 +168,7 @@ def _lm_cell(arch: str, cfg, shape_name: str, mesh: Mesh,
 # ------------------------------------------------------------------- GNN
 
 _GNN_FWD_FLOPS = {
-    # rough per-layer dense+edge costs (documented in benchmarks/roofline)
+    # rough per-layer dense+edge costs
     "gatedgcn": lambda cfg, n, e: cfg.n_layers * (5 * n * cfg.d_hidden ** 2
                                                   + 6 * e * cfg.d_hidden) * 2,
     "gat-cora": lambda cfg, n, e: (

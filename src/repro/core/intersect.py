@@ -389,10 +389,11 @@ def _probe_rows(adj, qu, qw, row_ok, *, d_cand, d_targ, backend, interpret,
     if bounds is None:
         bounds = (*adj.bounds(qu), *adj.bounds(qw))
     s_s, l_s, s_l, l_l = _swapped_bounds(*bounds, row_ok)
-    cand, targ, overflow = _gather_cand_targ(
-        adj.flat, s_s, l_s, s_l, l_l,
-        d_cand=d_cand, d_targ=d_targ, need_targ=(backend != "jnp"),
-    )
+    with jax.named_scope("gather"):
+        cand, targ, overflow = _gather_cand_targ(
+            adj.flat, s_s, l_s, s_l, l_l,
+            d_cand=d_cand, d_targ=d_targ, need_targ=(backend != "jnp"),
+        )
     if backend == "jnp":
         # search depth sized by d_targ over the UNclamped list — for exact
         # plans (d_targ >= every large degree) the search converges; for a
@@ -401,13 +402,15 @@ def _probe_rows(adj, qu, qw, row_ok, *, d_cand, d_targ, backend, interpret,
         num_steps = max(1, math.ceil(math.log2(d_targ + 1)))
         starts = jnp.broadcast_to(s_l[:, None], cand.shape)
         lens = jnp.broadcast_to(l_l[:, None], cand.shape)
-        found = bounded_binary_search(
-            adj.flat, starts, lens, cand, num_steps=num_steps
-        )
+        with jax.named_scope("compare"):
+            found = bounded_binary_search(
+                adj.flat, starts, lens, cand, num_steps=num_steps
+            )
         return cand, found & (cand >= 0) & row_ok[:, None], overflow
     from repro.kernels.intersect.intersect import intersect_pallas_hits
 
-    found = intersect_pallas_hits(cand, targ, interpret=interpret)
+    with jax.named_scope("compare"):
+        found = intersect_pallas_hits(cand, targ, interpret=interpret)
     return cand, found & row_ok[:, None], overflow
 
 
@@ -521,19 +524,22 @@ def _count_chunk(
         )
 
         s_s, l_s, s_l, l_l = _swapped_bounds(*bounds_c, row_ok)
-        cand, targ, overflow = _gather_cand_targ(
-            adj.flat, s_s, l_s, s_l, l_l,
-            d_cand=d_cand, d_targ=d_targ, need_targ=True,
-        )
+        with jax.named_scope("gather"):
+            cand, targ, overflow = _gather_cand_targ(
+                adj.flat, s_s, l_s, s_l, l_l,
+                d_cand=d_cand, d_targ=d_targ, need_targ=True,
+            )
         if level is None:
-            cnt = intersect_pallas_count(cand, targ, interpret=interpret)
+            with jax.named_scope("compare"):
+                cnt = intersect_pallas_count(cand, targ, interpret=interpret)
             return jnp.sum(cnt, dtype=jnp.int32), zero, overflow, None, acc
         lev_ext = jnp.concatenate([level, jnp.full((1,), -7, jnp.int32)])
         lev_c = jnp.where(cand >= 0, lev_ext[jnp.clip(cand, 0, n)], -7)
         lev_u = jnp.where(qu_c < n, lev_ext[jnp.clip(qu_c, 0, n)], -9)
-        c1, c2 = intersect_pallas(
-            cand, targ, lev_c, lev_u, interpret=interpret
-        )
+        with jax.named_scope("compare"):
+            c1, c2 = intersect_pallas(
+                cand, targ, lev_c, lev_u, interpret=interpret
+            )
         return (
             jnp.sum(c1, dtype=jnp.int32),
             jnp.sum(c2, dtype=jnp.int32),
@@ -661,12 +667,13 @@ def run_plan(
                 f"query_chunk={chunk} (plan the rows with row_mult=chunk)"
             )
         if chunk == b.rows:
-            d1, d2, do, dc, acc = _count_chunk(
-                adj, sliced[0], sliced[1], sliced[2:], 0, b.count,
-                d_cand=b.d_cand, d_targ=b.d_targ, level=level,
-                backend=plan.backend, interpret=plan.interpret,
-                per_vertex=per_vertex, acc=acc,
-            )
+            with jax.named_scope(f"probe_w{b.d_cand}"):
+                d1, d2, do, dc, acc = _count_chunk(
+                    adj, sliced[0], sliced[1], sliced[2:], 0, b.count,
+                    d_cand=b.d_cand, d_targ=b.d_targ, level=level,
+                    backend=plan.backend, interpret=plan.interpret,
+                    per_vertex=per_vertex, acc=acc,
+                )
             c1, c2, ovf = c1 + d1, c2 + d2, ovf | do
             if per_vertex:
                 credit = credit + dc
@@ -690,7 +697,8 @@ def run_plan(
                 )
 
             init = (c1, c2, ovf) + ((credit, acc) if per_vertex else ())
-            res = jax.lax.fori_loop(0, b.rows // chunk, body, init)
+            with jax.named_scope(f"probe_w{b.d_cand}"):
+                res = jax.lax.fori_loop(0, b.rows // chunk, body, init)
             c1, c2, ovf = res[:3]
             if per_vertex:
                 credit, acc = res[3], res[4]

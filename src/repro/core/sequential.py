@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.bfs import bfs_levels
 from repro.core.edges import horizontal_mask, horizontal_queries, k_fraction
 from repro.core.intersect import (
@@ -186,21 +187,44 @@ def _exact_batch_plan(
     first ``h_used = min(cap_h, max_lane_km)`` rows (``h_dropped`` is
     True iff ``cap_h`` cut real queries in some lane)."""
     level, qu, qw, ds_pool, dl_pool, n_h, k = _plan_batch(gview, root)
-    ds_h, dl_h, H = jax.device_get((ds_pool, dl_pool, jnp.max(n_h)))
+    with obs.span("tc.plan_sync"):
+        ds_h, dl_h, H = jax.device_get((ds_pool, dl_pool, jnp.max(n_h)))
     H = int(H)
     h_used = H if cap_h is None else min(int(cap_h), H)
-    plan = plan_buckets(
-        np.asarray(ds_h[:h_used]),
-        np.asarray(dl_h[:h_used]),
-        bucket_widths=bucket_widths,
-        d_cap=d_max,
-        row_mult=row_mult,
-        backend=backend,
-        interpret=interpret,
-        query_chunk=query_chunk,
-        layout="desc",
-    )
+    with obs.span("tc.plan_layout"):
+        plan = plan_buckets(
+            np.asarray(ds_h[:h_used]),
+            np.asarray(dl_h[:h_used]),
+            bucket_widths=bucket_widths,
+            d_cap=d_max,
+            row_mult=row_mult,
+            backend=backend,
+            interpret=interpret,
+            query_chunk=query_chunk,
+            layout="desc",
+        )
+    if qu.shape[0] == 1:
+        _count_gather_entries(plan, ds_h, dl_h)
     return level, qu, qw, n_h, k, h_used, h_used < H, plan
+
+
+def _count_gather_entries(plan, ds_h, dl_h):
+    """Add a one-lane exact plan's dense-gather volume to the ``probe.*``
+    counters: each bucket gathers ``rows × d_cand`` candidates and, on a
+    backend that compares against dense target lists (not ``jnp``,
+    which searches the CSR), ``rows × d_targ`` targets; of those, the
+    real entries are the planned rows' degrees, clipped to the bucket's
+    widths."""
+    targ = plan.backend != "jnp"
+    gathered = real = 0
+    for b in plan.buckets:
+        rows = slice(b.start, b.start + b.count)
+        gathered += b.rows * (b.d_cand + (b.d_targ if targ else 0))
+        real += int(np.minimum(ds_h[rows], b.d_cand).sum())
+        if targ:
+            real += int(np.minimum(dl_h[rows], b.d_targ).sum())
+    obs.incr("probe.entries_gathered", gathered)
+    obs.incr("probe.entries_real", real)
 
 
 # ----------------------------------------------------- batch plan cache
@@ -357,9 +381,10 @@ def _triangle_count_batch(
                 "d_max/cap_h only apply to exact planning; a precomputed "
                 "plan fixes coverage and widths"
             )
-        level, n_h, k, eng = _tc_batch_fused(
-            gview, plan, root, per_vertex=bool(o.per_vertex)
-        )
+        with obs.span("tc.probe"):
+            level, n_h, k, eng = _tc_batch_fused(
+                gview, plan, root, per_vertex=bool(o.per_vertex)
+            )
         # coverage is the plan's contract: a lane with more horizontal
         # queries than the plan probes must flag, not silently undercount
         # (can't happen with a plan from THIS batch's true-bound meta,
@@ -371,9 +396,10 @@ def _triangle_count_batch(
             gview, root, o.cap_h, o.bucket_widths, o.d_max, row_mult,
             backend, interpret, o.query_chunk,
         )
-        eng = _run_batch(
-            gview, qu, qw, level, plan, per_vertex=bool(o.per_vertex)
-        )
+        with obs.span("tc.probe"):
+            eng = _run_batch(
+                gview, qu, qw, level, plan, per_vertex=bool(o.per_vertex)
+            )
         h_ovf = (n_h > h_used) | eng.overflow
     return TCResult(
         triangles=eng.c1 + eng.c2 // 3,

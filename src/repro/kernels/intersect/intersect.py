@@ -25,6 +25,10 @@ never pay hub padding on the candidate side.
 Grid: (Q/BQ, Dc/BD, Dt/BD); the counter outputs are revisited across the
 inner two grid dims and accumulated in place (sequential TPU grid).
 
+Each launch is named ``intersect_<role>_w<candidate width>``
+(``intersect_split_w256``, ``intersect_count_w32``, ``intersect_hits_w4096``):
+the device trace then tells the kernels and the plan's buckets apart.
+
 TPU layout: every block is 2-D.  Per-query values (the apex level
 ``lev_u`` in, the counters out) travel as ``(Q, 1)`` columns with
 ``(BQ, 1)`` blocks — the Mosaic lowering refuses rank-1 blocks narrower
@@ -112,13 +116,14 @@ def _hits_kernel(cand_ref, targ_ref, hit_ref):
     _tile_hit(cand_ref, targ_ref, body)
 
 
-def _launch(kernel, cand, targ, extra, out_cols, *, block_q, block_d,
+def _launch(kernel, role, cand, targ, extra, out_cols, *, block_q, block_d,
             interpret):
     """Pad ``cand``/``targ`` (and the per-candidate / per-query ``extra``
     inputs) up to block multiples and run ``kernel`` over the
-    (Q/BQ, Dc/BD, Dt/BD) grid.  ``out_cols`` lists each int32 output's
-    column layout: ``1`` for a per-query ``(Q, 1)`` counter, ``"cand"``
-    for a ``(Q, Dc)`` per-candidate tile.  Returns the padded outputs."""
+    (Q/BQ, Dc/BD, Dt/BD) grid as ``intersect_<role>_w<Dc>``.
+    ``out_cols`` lists each int32 output's column layout: ``1`` for a
+    per-query ``(Q, 1)`` counter, ``"cand"`` for a ``(Q, Dc)``
+    per-candidate tile.  Returns the padded outputs."""
     q, dc = cand.shape
     dt = targ.shape[1]
     qp = -(-q // block_q) * block_q
@@ -163,6 +168,7 @@ def _launch(kernel, cand, targ, extra, out_cols, *, block_q, block_d,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name=f"intersect_{role}_w{dc}",
     )(*args)
 
 
@@ -183,7 +189,7 @@ def intersect_pallas(
     ``cand`` and ``targ`` may have different widths."""
     q = cand.shape[0]
     c1, c2 = _launch(
-        _kernel, cand, targ, [(lev_c, -7), (lev_u, -9)], [1, 1],
+        _kernel, "split", cand, targ, [(lev_c, -7), (lev_u, -9)], [1, 1],
         block_q=block_q, block_d=block_d, interpret=interpret,
     )
     return c1[:q, 0], c2[:q, 0]
@@ -210,7 +216,7 @@ def intersect_pallas_count(
     candidate is counted in at most one target tile.
     """
     (cnt,) = _launch(
-        _count_kernel, cand, targ, [], [1],
+        _count_kernel, "count", cand, targ, [], [1],
         block_q=block_q, block_d=block_d, interpret=interpret,
     )
     return cnt[: cand.shape[0], 0]
@@ -233,7 +239,7 @@ def intersect_pallas_hits(
     target grid dim and OR-accumulated in place."""
     q, dc = cand.shape
     (hit,) = _launch(
-        _hits_kernel, cand, targ, [], ["cand"],
+        _hits_kernel, "hits", cand, targ, [], ["cand"],
         block_q=block_q, block_d=block_d, interpret=interpret,
     )
     return hit[:q, :dc] > 0

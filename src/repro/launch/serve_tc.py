@@ -52,6 +52,7 @@ from typing import Optional, Sequence, Union
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core import sequential as seq
 from repro.core.intersect import DEFAULT_BUCKET_WIDTHS
 from repro.graph import generators as gen
@@ -659,6 +660,7 @@ class TriangleServer:
             self._finalize_one()
         return self.results
 
+    @obs.spanned("serve.flush")
     def _flush(self, budget: ShapeBudget, *, cause: str = "size") -> None:
         reqs = self._pending.pop(budget, [])
         if not reqs:
@@ -677,11 +679,12 @@ class TriangleServer:
         try:
             if self.faults is not None:
                 self.faults.before_batch(self.batches_run)
-            gb = from_edges_batch(
-                [(r.edges, r.n_nodes) for r in reqs],
-                budget=budget,
-                batch_size=lanes,
-            )
+            with obs.span("tc.pack"):
+                gb = from_edges_batch(
+                    [(r.edges, r.n_nodes) for r in reqs],
+                    budget=budget,
+                    batch_size=lanes,
+                )
             if gb.meta is not None:  # plan stability: one plan per
                 gb = dataclasses.replace(  # (cell, lane count), not one
                     gb, meta=self.engine.pool_meta(budget, gb.meta)
@@ -725,6 +728,7 @@ class TriangleServer:
         while self._inflight and self._batch_ready(self._inflight[0][2]):
             self._finalize_one()
 
+    @obs.spanned("serve.finalize")
     def _finalize_one(self) -> None:
         reqs, budget, res, t_flush = self._inflight.popleft()
         try:
