@@ -19,7 +19,10 @@ single engine in this module (DESIGN.md §2–§3):
   known as an upper bound — the shard_map case) both produce an
   ``IntersectPlan``: a tuple of contiguous query-row buckets, each with a
   static row count and candidate/target widths.  A plan is hashable and
-  jit-/shard_map-static.
+  jit-/shard_map-static.  An exact plan that gathers dense targets splits
+  a candidate bucket whose larger degrees straddle ``TARGET_BAND_EDGE``
+  (or its ×4 multiples) into target-width bands, so rows next to a hub
+  no longer pay the hub's target width.
 
 * **Execution.**  ``run_plan`` slices the (degree-sorted) query block at
   the plan's static boundaries and probes each bucket at its own padded
@@ -56,6 +59,12 @@ from repro.kernels.intersect.intersect import CAND_PAD, TARG_PAD
 #: endpoint has degree <= w probe at candidate width w (plus an implicit
 #: top bucket at the max/capped width).
 DEFAULT_BUCKET_WIDTHS = (32, 256)
+
+#: First target-width band boundary of an exact plan; the next ones are
+#: its ×4 multiples (512, 2048, 8192, ...).  A candidate bucket whose
+#: larger-endpoint degrees fall in more than one band is probed band by
+#: band, each at its own target width.
+TARGET_BAND_EDGE = 512
 
 
 # --------------------------------------------------------------- views
@@ -157,6 +166,11 @@ class IntersectPlan:
     #: host could not pre-sort).  Exact plans pre-sorted on the host
     #: leave this False.
     sort_queries: bool = False
+    #: row offsets where an exact plan's candidate buckets end, set only
+    #: when a bucket was split into target-width bands: ``run_plan``
+    #: then sorts each candidate bucket's rows by larger degree,
+    #: descending, before slicing the bands (``band_order``).
+    band_ends: tuple[int, ...] = ()
 
     @property
     def total_rows(self) -> int:
@@ -204,13 +218,24 @@ def plan_buckets(
     the bucket, 128-aligned.  Widths are rounded (pow2 top, 128-aligned
     ``d_targ``, ``row_mult``-padded rows) so same-scale graphs with
     different degree profiles share jit cache entries.
+
+    Target bands: where the backend gathers dense targets (not
+    ``"jnp"``) and some bucket's larger degrees fall in more than one
+    band (``TARGET_BAND_EDGE``, ×4 each), every bucket is emitted as one
+    bucket per non-empty band, widest band first, with ``d_targ`` the
+    band's widest list and ``d_cand = min(width, d_targ)`` (the probe
+    reads candidates from the smaller list, so ``ds <= dl <= d_targ``).
+    ``band_ends`` then tells ``run_plan`` to sort each bucket's rows by
+    larger degree, descending.  Under a pooled batch profile this stays
+    exact: each lane's k-th largest degree in a bucket is at most the
+    profile's k-th largest.  Otherwise the plan is the unbanded one.
     """
     if layout not in ("asc", "desc"):
         raise ValueError(f"layout must be 'asc' or 'desc'; got {layout!r}")
     ds_h = np.asarray(ds_h)
     dl_h = np.asarray(dl_h)
     H = int(ds_h.shape[0])
-    buckets = []
+    segments = []  # (lo, hi, width) of each non-empty candidate bucket
     if H:
         d_top = int(ds_h[-1] if layout == "asc" else ds_h[0])
         top = _next_pow2(max(d_top, 1))
@@ -235,19 +260,50 @@ def plan_buckets(
         for w, b in zip(widths, bounds):
             lo, hi = (b, start) if layout == "desc" else (start, b)
             start = b
-            if hi <= lo:
-                continue
+            if hi > lo:
+                segments.append((lo, hi, w))
+    bands = [
+        _target_bands(dl_h[lo:hi]) if backend != "jnp"
+        else [(hi - lo, int(dl_h[lo:hi].max()))]
+        for lo, hi, _ in segments
+    ]
+    banded = any(len(b) > 1 for b in bands)
+    buckets = []
+    for (lo, hi, w), seg_bands in zip(segments, bands):
+        for count, dl_max in seg_bands:
+            d_targ = _ceil_to(dl_max, 128)
             buckets.append(PlanBucket(
                 start=lo,
-                count=hi - lo,
-                rows=_ceil_to(hi - lo, row_mult),
-                d_cand=w,
-                d_targ=_ceil_to(int(dl_h[lo:hi].max()), 128),
+                count=count,
+                rows=_ceil_to(count, row_mult),
+                d_cand=min(w, d_targ) if banded else w,
+                d_targ=d_targ,
             ))
+            lo += count
+    ends = tuple(sorted(hi for _, hi, _ in segments)) if banded else ()
     return IntersectPlan(
         buckets=tuple(buckets), backend=backend, interpret=interpret,
-        query_chunk=query_chunk, sort_queries=False,
+        query_chunk=query_chunk, sort_queries=False, band_ends=ends,
     )
+
+
+def _target_bands(dl) -> list[tuple[int, int]]:
+    """``(rows, widest list)`` of one candidate bucket's larger degrees
+    in each non-empty target band, widest band first."""
+    top = int(dl.max())
+    edges = [TARGET_BAND_EDGE]
+    while edges[-1] < top:
+        edges.append(4 * edges[-1])
+    if len(edges) == 1:
+        return [(dl.size, top)]
+    # a band is a range of degrees, so its widest list is the widest at
+    # most its upper edge
+    upto = [0] + [int(np.count_nonzero(dl <= e)) for e in edges]
+    return [
+        (upto[i + 1] - upto[i], int(np.max(dl, where=dl <= e, initial=0)))
+        for i, e in reversed(list(enumerate(edges)))
+        if upto[i + 1] > upto[i]
+    ]
 
 
 def plan_buckets_bounded(
@@ -582,6 +638,24 @@ def _count_chunk(
     return c1, c2, overflow, ends, acc
 
 
+def band_order(plan: IntersectPlan, d_large: jnp.ndarray) -> jnp.ndarray:
+    """Row permutation that lays a query block out for a banded plan:
+    the rows of each candidate bucket (``plan.band_ends``) sorted by
+    larger-endpoint degree ``d_large``, descending and stable, so each
+    target band is a contiguous range, widest first.  No row leaves its
+    bucket; rows past the last bucket stay where they are."""
+    end = plan.band_ends[-1]
+    pos = jnp.arange(end, dtype=jnp.int32)
+    seg = jnp.searchsorted(
+        np.asarray(plan.band_ends, np.int32), pos, side="right"
+    ).astype(jnp.int32)
+    _, _, order = jax.lax.sort(
+        (seg, -d_large[:end], pos), num_keys=2, is_stable=True
+    )
+    tail = jnp.arange(end, d_large.shape[-1], dtype=jnp.int32)
+    return jnp.concatenate([order, tail])
+
+
 def run_plan(
     adj, qu, qw, plan: IntersectPlan, *, level=None, per_vertex=False
 ) -> EngineCounts:
@@ -590,9 +664,10 @@ def run_plan(
     ``qu``/``qw`` are the query endpoints (entries ``>= adj.n_nodes`` are
     sentinels and never counted); the block is padded to the plan's total
     rows and, for ``sort_queries`` plans, degree-sorted descending
-    in-trace.  Coverage is the *planner's* contract: rows beyond
-    ``plan.total_rows`` are deliberately not probed (that is how the
-    sequential pipeline skips the non-horizontal compacted tail and how
+    in-trace (``band_ends`` plans: each candidate bucket by larger
+    degree, ``band_order``).  Coverage is the *planner's* contract: rows
+    beyond ``plan.total_rows`` are deliberately not probed (that is how
+    the sequential pipeline skips the non-horizontal compacted tail and how
     ``cap_h`` truncates — the pipeline flags the latter as
     ``h_overflow``); a caller that wants full coverage must plan the full
     block.  Shapes depend only on ``(plan, len(qu))`` — never on the
@@ -643,9 +718,13 @@ def run_plan(
         order = jnp.argsort(-key)  # descending; invalid rows sort last
         qu, qw = qu[order], qw[order]
         su, lu, sw, lw = su[order], lu[order], sw[order], lw[order]
+    if plan.band_ends:
+        order = band_order(plan, jnp.maximum(lu, lw))
+        qu, qw = qu[order], qw[order]
+        su, lu, sw, lw = su[order], lu[order], sw[order], lw[order]
     zero = (qu[0] ^ qu[0]).astype(jnp.int32)  # device-varying under shard_map
     c1, c2, ovf = zero, zero, zero != 0
-    # note: sort_queries permutes the credit *scatter indices* along with
+    # note: both sorts permute the credit *scatter indices* along with
     # the queries — values travel with the sort, so attribution is
     # permutation-invariant
     credit = acc = None

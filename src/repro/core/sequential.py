@@ -62,6 +62,7 @@ from repro.core.intersect import (
     CsrAdjacency,
     IntersectPlan,
     _chunk_credit,
+    band_order,
     plan_buckets,
     plan_buckets_bounded,
     probe_block,
@@ -213,16 +214,28 @@ def _count_gather_entries(plan, ds_h, dl_h):
     counters: each bucket gathers ``rows × d_cand`` candidates and, on a
     backend that compares against dense target lists (not ``jnp``,
     which searches the CSR), ``rows × d_targ`` targets; of those, the
-    real entries are the planned rows' degrees, clipped to the bucket's
-    widths."""
+    real entries are the planned rows' degrees, the smaller clipped to
+    the candidate width (an exact plan's ``d_targ`` covers every larger
+    degree).  Real entries are summed per candidate bucket: a banded
+    bucket's rows are in ``run_plan``'s order only on the device, and
+    within the bucket each row's band clips its smaller degree as the
+    bucket's widest candidate width does (``ds <= dl <= d_targ``)."""
     targ = plan.backend != "jnp"
+    ends = plan.band_ends
+    width = {}  # (lo, hi) of each candidate bucket -> candidate width
     gathered = real = 0
     for b in plan.buckets:
-        rows = slice(b.start, b.start + b.count)
         gathered += b.rows * (b.d_cand + (b.d_targ if targ else 0))
-        real += int(np.minimum(ds_h[rows], b.d_cand).sum())
+        if ends:
+            i = int(np.searchsorted(ends, b.start, "right"))
+            rows = (ends[i - 1] if i else 0, ends[i])
+        else:
+            rows = (b.start, b.start + b.count)
+        width[rows] = max(width.get(rows, 0), b.d_cand)
+    for (lo, hi), w in width.items():
+        real += int(np.minimum(ds_h[lo:hi], w).sum())
         if targ:
-            real += int(np.minimum(dl_h[rows], b.d_targ).sum())
+            real += int(dl_h[lo:hi].sum())
     obs.incr("probe.entries_gathered", gathered)
     obs.incr("probe.entries_real", real)
 
@@ -680,6 +693,12 @@ def _find_triangles(g: Graph, o, *, max_triangles: int):
             stacklevel=2,
         )
     level, qu, qw = level[0], qu[0], qw[0]
+    if plan.band_ends:
+        adj = CsrAdjacency.from_graph(g)
+        order = band_order(
+            plan, jnp.maximum(adj.bounds(qu)[1], adj.bounds(qw)[1])
+        )
+        qu, qw = qu[order], qw[order]
     # dispatch EVERY bucket's jitted probe before the first fetch: the
     # device works through the blocks back-to-back while the host copies
     # results out, instead of stalling on a device_get per bucket

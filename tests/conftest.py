@@ -49,6 +49,22 @@ def nx_triangles(edges: np.ndarray, n: int) -> int:
     return sum(nx.triangles(G).values()) // 3
 
 
+def hub_graph(clique: int = 10, hubs=(600, 2500)) -> tuple[np.ndarray, int]:
+    """A ``clique`` (the BFS root is vertex 0) with one hub per entry of
+    ``hubs``, each joined to every clique vertex and to that many leaves
+    of its own.  The horizontal edges of BFS level 1 are the clique's
+    and the hubs' edges to it, so one candidate bucket holds larger
+    degrees on both sides of 512 — the exact plan's target bands."""
+    edges = [(i, j) for i in range(clique) for j in range(i + 1, clique)]
+    nxt = clique
+    for leaves in hubs:
+        hub, nxt = nxt, nxt + 1
+        edges += [(hub, i) for i in range(clique)]
+        edges += [(hub, nxt + k) for k in range(leaves)]
+        nxt += leaves
+    return np.asarray(edges, dtype=np.int64), nxt
+
+
 FIXTURES = {
     "karate": gen.karate(),
     "ring_of_cliques": gen.ring_of_cliques(5, 6),

@@ -8,8 +8,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import hub_graph
+from tests import oracle
+
+from repro.api import TCOptions, TriangleEngine
+from repro.core import intersect
+from repro.core import sequential as seq
 from repro.core.intersect import (
+    IntersectPlan,
+    PlanBucket,
     count_common_neighbors,
+    plan_buckets,
     probe_block,
     resolve_backend,
 )
@@ -20,7 +29,13 @@ from repro.core.sequential import (
     triangle_count_dense,
 )
 from repro.graph import generators as gen
-from repro.graph.csr import from_edges, max_degree
+from repro.graph.csr import (
+    GraphBatch,
+    from_edges,
+    from_edges_batch,
+    max_degree,
+    to_batch,
+)
 
 BACKENDS = ("jnp", "pallas")
 
@@ -186,3 +201,147 @@ def test_resolve_backend():
     assert resolve_backend("pallas", False) == ("pallas", False)
     with pytest.raises(ValueError):
         resolve_backend("cuda")
+
+
+# ------------------------------------------------------- target bands
+
+#: a desc-layout profile (small degrees descending) whose w512 and w256
+#: buckets hold larger degrees in three bands, in mixed order
+HUB_DS = np.array([300, 300, 300, 100, 100, 100, 100, 10, 10])
+HUB_DL = np.array([600, 3000, 400, 100, 5000, 2000, 100, 20, 50])
+
+
+def test_band_layout_by_hand():
+    plan = plan_buckets(HUB_DS, HUB_DL, backend="pallas", layout="desc")
+    assert plan.band_ends == (3, 7, 9)
+    assert plan.buckets == (
+        PlanBucket(start=7, count=2, rows=64, d_cand=32, d_targ=128),
+        PlanBucket(start=3, count=1, rows=64, d_cand=256, d_targ=5120),
+        PlanBucket(start=4, count=1, rows=64, d_cand=256, d_targ=2048),
+        PlanBucket(start=5, count=2, rows=64, d_cand=128, d_targ=128),
+        PlanBucket(start=0, count=1, rows=64, d_cand=512, d_targ=3072),
+        PlanBucket(start=1, count=1, rows=64, d_cand=512, d_targ=640),
+        PlanBucket(start=2, count=1, rows=64, d_cand=512, d_targ=512),
+    )
+
+
+def test_narrow_targets_keep_the_unbanded_plan():
+    """Larger degrees all at most 512 (a skew-free graph): the plan is
+    the one without bands, widths and all."""
+    dl = np.minimum(HUB_DL, 512)
+    plan = plan_buckets(HUB_DS, dl, backend="pallas", layout="desc")
+    assert plan == IntersectPlan(
+        buckets=(
+            PlanBucket(start=7, count=2, rows=64, d_cand=32, d_targ=128),
+            PlanBucket(start=3, count=4, rows=64, d_cand=256, d_targ=512),
+            PlanBucket(start=0, count=3, rows=64, d_cand=512, d_targ=512),
+        ),
+        backend="pallas",
+    )
+
+
+def test_jnp_plans_never_band():
+    """The jnp probe searches the CSR and gathers no targets."""
+    plan = plan_buckets(HUB_DS, HUB_DL, backend="jnp", layout="desc")
+    assert plan.band_ends == ()
+    assert [(b.start, b.count, b.d_cand, b.d_targ) for b in plan.buckets] == [
+        (7, 2, 32, 128), (3, 4, 256, 5120), (0, 3, 512, 3072)]
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_bands_cover_their_rows(seed):
+    """Heavy-tailed random profiles: in ``run_plan``'s row order every
+    band's rows fit its widths, bands tile each candidate bucket, and
+    ``d_cand <= d_targ``."""
+    rng = np.random.default_rng(seed)
+    a = np.minimum(rng.pareto(0.8, 3000).astype(np.int64) + 1, 20_000)
+    b = np.minimum(rng.pareto(0.8, 3000).astype(np.int64) + 1, 20_000)
+    ds, dl = np.minimum(a, b), np.maximum(a, b)
+    order = np.argsort(-ds, kind="stable")
+    ds, dl = ds[order], dl[order]
+    plan = plan_buckets(ds, dl, backend="pallas", layout="desc",
+                        row_mult=32)
+    assert plan.band_ends
+    perm = np.asarray(intersect.band_order(plan, jnp.asarray(dl)))
+    assert sorted(perm.tolist()) == list(range(ds.size))
+    ds_p, dl_p = ds[perm], dl[perm]
+    covered = np.zeros(ds.size, int)
+    for bk in plan.buckets:
+        rows = slice(bk.start, bk.start + bk.count)
+        assert bk.d_cand <= bk.d_targ and bk.rows % 32 == 0
+        assert ds_p[rows].max() <= bk.d_cand
+        assert dl_p[rows].max() <= bk.d_targ
+        covered[rows] += 1
+    assert (covered == 1).all()
+    seg = np.searchsorted(plan.band_ends, np.arange(ds.size), "right")
+    assert (seg[perm] == seg).all()  # no row leaves its candidate bucket
+
+
+def _pallas(**kw):
+    return TCOptions(backend="pallas", interpret=True, **kw)
+
+
+def _exact_plan(g, o):
+    """The exact plan a count of ``g`` (a graph or a batch) runs."""
+    gb = g if isinstance(g, GraphBatch) else to_batch(g)
+    return seq._exact_batch_plan(
+        gb.lane_view(), 0, o.cap_h, o.bucket_widths, o.d_max,
+        int(o.query_chunk or o.row_mult), "pallas", True, o.query_chunk,
+    )[-1]
+
+
+@pytest.fixture(scope="module")
+def hubs():
+    edges, n = hub_graph()
+    return edges, n, from_edges(edges, n)
+
+
+@pytest.mark.parametrize("chunk", [None, 32, 64])
+def test_banded_count_and_per_vertex_exact(hubs, chunk):
+    """Pallas (interpreted) over a plan whose w16 bucket splits into
+    three target bands; ``query_chunk`` 64 equals every band's rows, 32
+    is smaller than the 36-row band's."""
+    edges, n, g = hubs
+    o = _pallas(query_chunk=chunk, per_vertex=True)
+    assert len(_exact_plan(g, o).band_ends) == 1
+    assert len(_exact_plan(g, o).buckets) == 3
+    rep = TriangleEngine(o).count(g, route="local")
+    assert rep.triangles == oracle.total_triangles(edges, n) == 210
+    assert not rep.overflow
+    np.testing.assert_array_equal(
+        np.asarray(rep.per_vertex), oracle.triangle_counts(edges, n))
+
+
+def test_banded_find_exact(hubs):
+    edges, n, g = hubs
+    tri, cnt = TriangleEngine(_pallas()).find_raw(g, max_triangles=512)
+    tri_d, cnt_d = find_triangles_dense(
+        g, d_max=max_degree(g), max_triangles=512)
+    assert int(cnt) == int(cnt_d) == 210
+    assert _tri_set(tri, cnt) == _tri_set(tri_d, cnt_d)
+
+
+def test_banded_pooled_batch_exact():
+    """Two lanes with different hubs share one plan laid out from their
+    pooled per-row maxima; each lane sorts its own rows."""
+    graphs = [hub_graph(10, (600, 2500)), hub_graph(12, (1500,))]
+    gb = from_edges_batch(graphs)
+    assert _exact_plan(gb, _pallas()).band_ends
+    res = TriangleEngine(_pallas()).count_batch_raw(gb)
+    for i, (edges, n) in enumerate(graphs):
+        assert int(res.triangles[i]) == oracle.total_triangles(edges, n)
+        assert not bool(res.h_overflow[i])
+
+
+def test_banded_overflow_matches_unbanded(hubs, monkeypatch):
+    """Under an explicit ``d_max`` that clips candidate lists, the
+    banded plan gives the unbanded plan's count and overflow flag."""
+    edges, n, g = hubs
+    o = _pallas(d_max=8)
+    assert _exact_plan(g, o).band_ends
+    banded = TriangleEngine(o).count(g, route="local")
+    monkeypatch.setattr(intersect, "TARGET_BAND_EDGE", 1 << 30)
+    assert not _exact_plan(g, o).band_ends
+    flat = TriangleEngine(o).count(g, route="local")
+    assert banded.overflow and flat.overflow
+    assert banded.triangles == flat.triangles < 210

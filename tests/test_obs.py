@@ -107,7 +107,11 @@ def _expected_entries(edges, n, levels, backend, widths=(32, 256),
                       row_mult=64):
     """``(gathered, real)`` of one exact count, recomputed from the
     graph: its horizontal edges, their endpoints' degrees, and the
-    bucket layout (widths, row padding, 128-aligned target width)."""
+    bucket layout (widths, row padding, 128-aligned target width).
+    Where targets are gathered and some bucket's larger degrees fall on
+    both sides of 512 (or of 2048, 8192, ...), every bucket is gathered
+    band by band: each band at its widest target and at the smaller of
+    the bucket's width and that target."""
     e = np.sort(np.asarray(edges, np.int64), axis=1)
     e = np.unique(e[e[:, 0] != e[:, 1]], axis=0)
     deg = np.bincount(e.ravel(), minlength=n)
@@ -117,14 +121,24 @@ def _expected_entries(edges, n, levels, backend, widths=(32, 256),
     top = 1 << max(0, int(ds.max()) - 1).bit_length()
     bounds = [w for w in widths if w < top] + [top]
     targ = backend != "jnp"
-    gathered, lo = 0, 0
+    band = np.zeros(dl.size, int)
+    for edge in 512 * 4 ** np.arange(8):
+        band += dl > edge
+    buckets, lo = [], 0
     for w in bounds:
         rows = (ds > lo) & (ds <= w)
         lo = w
         if rows.any():
-            d_targ = _ceil_to(int(dl[rows].max()), 128)
-            gathered += _ceil_to(int(rows.sum()), row_mult) * (
-                w + (d_targ if targ else 0))
+            buckets.append((w, rows))
+    banded = targ and any(len(set(band[r])) > 1 for _, r in buckets)
+    gathered = 0
+    for w, rows in buckets:
+        for b in set(band[rows]) if banded else (None,):
+            sel = rows & (band == b) if banded else rows
+            d_targ = _ceil_to(int(dl[sel].max()), 128)
+            d_cand = min(w, d_targ) if banded else w
+            gathered += _ceil_to(int(sel.sum()), row_mult) * (
+                d_cand + (d_targ if targ else 0))
     real = int(ds.sum()) + (int(dl.sum()) if targ else 0)
     return gathered, real
 
@@ -138,6 +152,24 @@ def test_gather_counters_match_numpy(backend):
     c = obs.counters()
     gathered, real = _expected_entries(edges, n, rep.levels, backend)
     assert gathered > real > 0
+    assert c["probe.entries_gathered"] == gathered
+    assert c["probe.entries_real"] == real
+
+
+def test_gather_counters_match_numpy_with_bands():
+    """A clique with hubs of 600 and 2,500 leaves: the Pallas plan
+    gathers its one candidate bucket in three target bands, and the
+    counters still equal the recount."""
+    from conftest import hub_graph
+
+    edges, n = hub_graph()
+    engine = TriangleEngine(TCOptions(backend="pallas", interpret=True))
+    obs.reset()
+    rep = engine.count((edges, n))
+    c = obs.counters()
+    gathered, real = _expected_entries(edges, n, rep.levels, "pallas")
+    assert (gathered, real) == (64 * (16 + 2560 + 16 + 640 + 16 + 128),
+                                9 * (11 + 2510) + 9 * (11 + 610) + 36 * 22)
     assert c["probe.entries_gathered"] == gathered
     assert c["probe.entries_real"] == real
 

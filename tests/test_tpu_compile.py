@@ -1,7 +1,8 @@
 """The intersect kernels compile for a TPU v5e that is described, not
 attached: the TPU compiler is installed, so Mosaic's layout and VMEM
 checks run here at the widths the RMAT scale-16 plan gives the kernels
-(rows 4096; candidate widths 32, 256 and 8192; target width 9856).
+(rows 4096; candidate widths 32, 256 and 8192; target width 9856), and
+at the target bands of the GAP Kron scale-15 plan.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU library, and under several
@@ -78,6 +79,24 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, d_cand):
     used = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes)
     assert 0 < used < HBM_BYTES
+
+
+@pytest.mark.parametrize(
+    "d_cand,d_targ", [(512, 512), (32, 1280), (1280, 1280), (4096, 6016)]
+)
+def test_band_shapes_compile_for_v5e(one_chip, d_cand, d_targ):
+    """The level-split kernel at the (candidate, target) widths of the
+    Kron scale-15 plan's target bands: a 1,280-wide candidate list is
+    not a power of two, and 6,016 is the hub band's target width."""
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    args = [sds((ROWS, d_cand)), sds((ROWS, d_targ)),
+            sds((ROWS, d_cand)), sds((ROWS,))]
+    compiled = jax.jit(KERNELS["level_split"]).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
 
 
 def test_count_kernel_compiles_under_shard_map(topo):
