@@ -16,7 +16,9 @@ count), walk the lowered shard_map jaxpr and
 * **unpriced detection** — any equation over the mesh axis whose
   primitive is NOT in the priced set (``COLLECTIVE_PRIMITIVES``) is an
   error outright: the wire model has no formula for it, so the PR 4
-  modeled-vs-measured contract is silently broken;
+  modeled-vs-measured contract is silently broken.  Axis casts
+  (``AXIS_CASTS``: ``pvary`` lowers to the identity) move nothing and
+  are not collectives; one finding per distinct primitive and path;
 * **tally cross-check** — the per-phase byte totals folded from the
   inventory must equal the in-trace analytic ``CommTally`` formulas
   for the same capacities (exact, per phase).  At ``p == 1`` both
@@ -30,6 +32,7 @@ Nothing executes: programs are lowered from ShapeDtypeStructs.
 """
 from __future__ import annotations
 
+import collections
 import hashlib
 from typing import Iterable, Optional
 
@@ -38,6 +41,7 @@ import jax
 from repro.analysis.findings import Finding, finding_data
 from repro.analysis.routes import RouteSpec
 from repro.analysis.walker import (
+    AXIS_CASTS,
     COLLECTIVE_PRIMITIVES,
     collective_eqns,
     iter_eqns,
@@ -72,10 +76,11 @@ def census_digest(sites) -> str:
 def unpriced_collectives(closed_jaxpr, *, axis_name: str = "p"
                          ) -> list[str]:
     """Primitives communicating over the mesh axis that the wire model
-    has no price for — each is ``"primitive@path"``."""
+    has no price for — each is ``"primitive@path"``, once per
+    occurrence."""
     out = []
     for es in iter_eqns(unwrap(closed_jaxpr)):
-        if es.primitive in COLLECTIVE_PRIMITIVES:
+        if es.primitive in COLLECTIVE_PRIMITIVES + AXIS_CASTS:
             continue
         if uses_axis(es.eqn, axis_name):
             out.append(f"{es.primitive}@{'/'.join(es.path) or '<top>'}")
@@ -99,18 +104,20 @@ def audit_program_collectives(
     """All collective findings for one lowered shard program."""
     findings: list[Finding] = []
 
-    for site in unpriced_collectives(closed_jaxpr, axis_name=axis_name):
+    unpriced = collections.Counter(
+        unpriced_collectives(closed_jaxpr, axis_name=axis_name))
+    for site, times in unpriced.items():
         findings.append(Finding(
             pass_name="collectives",
             site=f"unpriced:{label}:{site}",
             severity="error",
             detail=(
-                f"collective `{site}` in {label} communicates over the "
-                f"mesh axis but is not in the priced set "
+                f"collective `{site}` ({times}x) in {label} communicates "
+                f"over the mesh axis but is not in the priced set "
                 f"{COLLECTIVE_PRIMITIVES} — the wire model cannot "
                 f"account for it"
             ),
-            data=finding_data(label=label, site=site),
+            data=finding_data(label=label, site=site, times=times),
         ))
 
     sites = collect_collective_sites(

@@ -29,8 +29,14 @@ import dataclasses
 from typing import Any, Iterator
 
 #: jaxpr primitive names that move data across a device axis.
+#: ``psum_invariant`` is the all-reduce JAX binds for ``psum`` of a
+#: device-varying value under ``shard_map``'s varying-axes checks.
 COLLECTIVE_PRIMITIVES = ("all_gather", "all_to_all", "ppermute",
-                         "psum", "pmax", "pmin")
+                         "psum", "psum_invariant", "pmax", "pmin")
+
+#: primitives that name a mesh axis but move no data: ``pvary`` marks a
+#: value as device-varying and lowers to the identity.
+AXIS_CASTS = ("pvary",)
 
 #: jaxpr primitive names that re-enter Python from inside a trace —
 #: each is a host round-trip (and a serialization barrier) if it ever

@@ -680,7 +680,7 @@ class TriangleEngine:
         mesh = mesh if mesh is not None else self.mesh
         o = self._resolve_hedge_mode(g, mesh, axis_name, o)
         return _ptc._parallel_triangle_count(g, mesh, axis_name=axis_name,
-                                             options=o)
+                                             options=o)[0]
 
     def _resolve_hedge_mode(
         self, g: Graph, mesh, axis_name: str, o: TCOptions
@@ -808,8 +808,9 @@ class TriangleEngine:
             # resolve the hedge mode BEFORE building the report so the
             # provenance (options.mode, plan_id) records the mode that ran
             o = self._resolve_hedge_mode(g, self.mesh, "p", o)
-            res = self.count_distributed_raw(g, options=o)
-            return self._report_distributed(res, o, deg=g.deg)
+            res, plan = _ptc._parallel_triangle_count(g, self.mesh,
+                                                      options=o)
+            return self._report_distributed(res, o, plan=plan, deg=g.deg)
         raise ValueError(f"unroutable request (route={r!r})")
 
     def count_batch(
@@ -1009,14 +1010,28 @@ class TriangleEngine:
         )
 
     def _report_distributed(
-        self, res: "_ptc.ParallelTCResult", o: TCOptions, *, deg=None
+        self,
+        res: "_ptc.ParallelTCResult",
+        o: TCOptions,
+        *,
+        plan: IntersectPlan,
+        deg=None,
     ) -> TriangleReport:
-        tri, nh, k, t_ovf, h_ovf, pd = jax.device_get(
-            (res.triangles, res.num_horizontal, res.k,
-             res.transpose_overflow, res.hedge_overflow, res.per_device)
-        )
+        with obs.span("tc.fetch"):
+            tri, nh, k, t_ovf, h_ovf, pd, comm = jax.device_get(
+                (res.triangles, res.num_horizontal, res.k,
+                 res.transpose_overflow, res.hedge_overflow, res.per_device,
+                 res.comm)
+            )
         backend, _ = resolve_backend(o.backend, o.interpret)
         p = pd.shape[0]
+        # every device probes ``plan`` once a round: one round after the
+        # all-gather, p in the ring; each horizontal edge once per device
+        rounds = p if o.mode == "ring" else 1
+        obs.incr("dist.counts")
+        obs.incr("dist.rows_planned", p * rounds * plan.probe_rows)
+        obs.incr("dist.rows_real", p * int(nh))
+        obs.incr("dist.wire_bytes", comm.total)
         pv = degs = None
         if res.per_vertex is not None and deg is not None:
             pv, degs = (
@@ -1028,7 +1043,7 @@ class TriangleEngine:
             overflow=Overflow(transpose=bool(t_ovf), hedge=bool(h_ovf)),
             route="distributed", backend=backend,
             plan_id=f"hedge/{o.mode}/p{p}", options=o,
-            comm=res.comm, per_device=np.asarray(pd),
+            comm=comm, per_device=np.asarray(pd),
             per_vertex=pv, degrees=degs,
         )
 

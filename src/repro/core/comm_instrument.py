@@ -56,6 +56,9 @@ from repro.core.comm_model import (
 )
 
 _REDUCE_PRIMS = ("psum", "pmax", "pmin")
+#: the all-reduce JAX binds for ``psum`` of a device-varying value; the
+#: inventory records it as the ``psum`` it is
+_PSUM_ALIASES = {"psum_invariant": "psum"}
 
 
 #: Largest per-field value the in-trace tally stores.  A phase beyond
@@ -197,6 +200,7 @@ def collect_collective_sites(
             es.eqn, axis_name
         ):
             continue
+        name = _PSUM_ALIASES.get(name, name)
         aval = es.eqn.invars[0].aval
         nbytes = int(math.prod(aval.shape)) * aval.dtype.itemsize
         sites.append(_price_site(

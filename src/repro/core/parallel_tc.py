@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.bfs import UNVISITED, bfs_levels
 from repro.core.comm_instrument import CommTally, tally_comm
 from repro.core.edges import horizontal_mask, mindeg_exceedance
@@ -233,45 +234,51 @@ def _tc_shard(
     when adding or removing a scalar psum/pmax here)."""
     inf = n + 1
     # ---- line 2: parallel BFS + horizontal marking -------------------
-    level = bfs_levels(src_i, dst_i, n, root=root, axis_name=axis_name,
-                       frontier_dtype=frontier_dtype)
-    horiz = horizontal_mask(src_i, dst_i, level, n)
+    with jax.named_scope("bfs"):
+        level = bfs_levels(src_i, dst_i, n, root=root, axis_name=axis_name,
+                           frontier_dtype=frontier_dtype)
+        horiz = horizontal_mask(src_i, dst_i, level, n)
     valid = (src_i < n) & (dst_i < n)
 
     # ---- lines 3-5: modified neighborhoods N-hat ---------------------
     keep = valid & ~(horiz & (src_i < dst_i))
     # ---- lines 6-28: sample-sort transpose by neighbor value ---------
-    rep = repartition_by_value(
-        values=jnp.where(keep, dst_i, inf),
-        carry=jnp.where(keep, src_i, inf),
-        valid=keep,
-        p=p,
-        cap_chunk=cap_chunk,
-        axis_name=axis_name,
-        inf=inf,
-    )
+    with jax.named_scope("transpose"):
+        rep = repartition_by_value(
+            values=jnp.where(keep, dst_i, inf),
+            carry=jnp.where(keep, src_i, inf),
+            valid=keep,
+            p=p,
+            cap_chunk=cap_chunk,
+            axis_name=axis_name,
+            inf=inf,
+        )
     # received pairs (owner v = carry, value x) sorted by (v, x) — exactly
     # the engine's pair-list adjacency view; sublist(v) is a sorted slice
     adj = PairListAdjacency(owners=rep.carry, values=rep.values, n_nodes=n)
 
     # ---- lines 29-43: horizontal-edge exchange + planned intersections
     is_h = horiz & (src_i < dst_i)
-    order = jnp.argsort(~is_h, stable=True)
-    hv = jnp.where(is_h[order], src_i[order], inf)[:cap_hedge]
-    hw = jnp.where(is_h[order], dst_i[order], inf)[:cap_hedge]
-    n_h_local = jnp.sum(is_h, dtype=jnp.int32)
-    hedge_overflow = (
-        jax.lax.pmax((n_h_local > cap_hedge).astype(jnp.int32), axis_name) > 0
-    )
+    with jax.named_scope("hedge"):
+        order = jnp.argsort(~is_h, stable=True)
+        hv = jnp.where(is_h[order], src_i[order], inf)[:cap_hedge]
+        hw = jnp.where(is_h[order], dst_i[order], inf)[:cap_hedge]
+        n_h_local = jnp.sum(is_h, dtype=jnp.int32)
+        hedge_overflow = (
+            jax.lax.pmax((n_h_local > cap_hedge).astype(jnp.int32),
+                         axis_name) > 0
+        )
 
     # fori_loop carries must be device-varying from the start (shard_map vma)
     t0 = jax.lax.pvary(jnp.int32(0), (axis_name,))
     o0 = jax.lax.pvary(jnp.bool_(False), (axis_name,))
     if mode == "allgather":
         # one collective, volume k·m·p — identical to the paper's p rounds
-        all_hv = jax.lax.all_gather(hv, axis_name).reshape(-1)
-        all_hw = jax.lax.all_gather(hw, axis_name).reshape(-1)
-        eng = run_plan(adj, all_hv, all_hw, hplan, per_vertex=per_vertex)
+        with jax.named_scope("hedge"):
+            all_hv = jax.lax.all_gather(hv, axis_name).reshape(-1)
+            all_hw = jax.lax.all_gather(hw, axis_name).reshape(-1)
+        with jax.named_scope("probe"):
+            eng = run_plan(adj, all_hv, all_hw, hplan, per_vertex=per_vertex)
         t_i = t0 + eng.c1
         d_ovf = o0 | eng.overflow
         credit = eng.per_vertex
@@ -283,13 +290,16 @@ def _tc_shard(
         # k·m wire for nothing (and breaking the wire-volume equality
         # with allgather mode that the comm instrument asserts).
         perm = [(i, (i + 1) % p) for i in range(p)]
-        eng0 = run_plan(adj, hv, hw, hplan, per_vertex=per_vertex)
+        with jax.named_scope("probe"):
+            eng0 = run_plan(adj, hv, hw, hplan, per_vertex=per_vertex)
 
         def round_body(r, carry):
             t, o, cv, cw = carry[:4]
-            cv = jax.lax.ppermute(cv, axis_name, perm)
-            cw = jax.lax.ppermute(cw, axis_name, perm)
-            eng = run_plan(adj, cv, cw, hplan, per_vertex=per_vertex)
+            with jax.named_scope("hedge"):
+                cv = jax.lax.ppermute(cv, axis_name, perm)
+                cw = jax.lax.ppermute(cw, axis_name, perm)
+            with jax.named_scope("probe"):
+                eng = run_plan(adj, cv, cw, hplan, per_vertex=per_vertex)
             out = (t + eng.c1, o | eng.overflow, cv, cw)
             return out + (
                 (carry[4] + eng.per_vertex,) if per_vertex else ()
@@ -304,19 +314,21 @@ def _tc_shard(
     else:
         raise ValueError(mode)
 
-    d_overflow = jax.lax.pmax(d_ovf.astype(jnp.int32), axis_name) > 0
-
     # ---- line 44: reduction -------------------------------------------
-    T = jax.lax.psum(t_i, axis_name)
-    # per-vertex credit is shard-local partials under N-hat's exactly-once
-    # semantics: one n-vector psum (the "one extra collective" of the
-    # attribution feature — priced as phase "reduce" by the tally AND
-    # the HLO pricer; drop the engine's sentinel slot before reducing)
-    pv = (
-        jax.lax.psum(credit[:n], axis_name) if per_vertex else None
-    )
-    n_h = jax.lax.psum(n_h_local, axis_name)
-    m = jax.lax.psum(jnp.sum(valid & (src_i < dst_i), dtype=jnp.int32), axis_name)
+    with jax.named_scope("reduce"):
+        d_overflow = jax.lax.pmax(d_ovf.astype(jnp.int32), axis_name) > 0
+        T = jax.lax.psum(t_i, axis_name)
+        # per-vertex credit is shard-local partials under N-hat's
+        # exactly-once semantics: one n-vector psum (the "one extra
+        # collective" of the attribution feature — priced as phase
+        # "reduce" by the tally AND the HLO pricer; drop the engine's
+        # sentinel slot before reducing)
+        pv = (
+            jax.lax.psum(credit[:n], axis_name) if per_vertex else None
+        )
+        n_h = jax.lax.psum(n_h_local, axis_name)
+        m = jax.lax.psum(jnp.sum(valid & (src_i < dst_i), dtype=jnp.int32),
+                         axis_name)
     k = n_h / jnp.maximum(m, 1)
     # every BFS sweep ran one frontier pmax and assigned level cur+1 to
     # at least one vertex (reseeds included), so sweeps = max level + 1;
@@ -389,13 +401,33 @@ def build_tc_shard_fn(
     return fn, cap_edges
 
 
+@functools.partial(jax.jit, static_argnames=("mesh", "body"))
+def _tc_distributed(src, dst, *, mesh: Mesh, body: tuple) -> ParallelTCResult:
+    """Algorithm 2 as one program: ``_tc_shard`` on every device of
+    ``mesh``'s one axis.  ``body`` is ``_tc_shard``'s static keywords as
+    sorted ``(name, value)`` pairs (n, p, root, capacities, the plan,
+    mode, frontier dtype, per_vertex, axis name); with the mesh it keys
+    the program, so repeated counts of graphs of one static shape trace
+    and lower it once.  Device traces name the program after this
+    function."""
+    kw = dict(body)
+    axis = kw["axis_name"]
+    return jax.shard_map(
+        functools.partial(_tc_shard, **kw),
+        mesh=mesh,
+        in_specs=(P(axis), P(axis)),
+        out_specs=result_out_specs(axis, per_vertex=kw["per_vertex"]),
+    )(src, dst)
+
+
 def _parallel_triangle_count(
     g: Graph, mesh: Mesh, *, axis_name: str = "p", options
-) -> ParallelTCResult:
+) -> tuple[ParallelTCResult, IntersectPlan]:
     """Algorithm 2 impl — ``options`` is a ``repro.api.TCOptions`` with
     ``mode`` already resolved to ``"allgather"`` or ``"ring"`` (the
     ``"auto"`` hedge-mode policy lives in the engine,
-    ``TriangleEngine.count_distributed_raw``)."""
+    ``TriangleEngine.count_distributed_raw``).  Returns the result and
+    the horizontal-round plan every device ran."""
     o = options
     if o.mode not in ("allgather", "ring"):
         raise ValueError(
@@ -412,12 +444,17 @@ def _parallel_triangle_count(
     # shard once: the same host-side pass feeds the shard_map inputs AND
     # the ring plan's per-shard degree bounds
     cap_edges = _capacities(m2, p, slack)[0]
-    s_sh, d_sh, _, _ = shard_edges(g, p, capacity=cap_edges)
-    hplan = plan_hedge_rounds(
-        g, p, mode=mode, hedge_chunk=hedge_chunk, d_pad=d_pad,
-        bucket_widths=bucket_widths, intersect_backend=backend,
-        interpret=interpret, shards=(s_sh, d_sh),
-    )
+    sharding = NamedSharding(mesh, P(axis_name))
+    with obs.span("tc.shard"):
+        s_sh, d_sh, _, _ = shard_edges(g, p, capacity=cap_edges)
+        s_dev = jax.device_put(s_sh.reshape(-1), sharding)
+        d_dev = jax.device_put(d_sh.reshape(-1), sharding)
+    with obs.span("tc.plan_layout"):
+        hplan = plan_hedge_rounds(
+            g, p, mode=mode, hedge_chunk=hedge_chunk, d_pad=d_pad,
+            bucket_widths=bucket_widths, intersect_backend=backend,
+            interpret=interpret, shards=(s_sh, d_sh),
+        )
     # every resolved knob goes to the builder: with hplan given the
     # backend pair only seeds the (unused) fallback plan, but dropping
     # them here is exactly how a future fallback path would silently
@@ -428,16 +465,10 @@ def _parallel_triangle_count(
         intersect_backend=backend, interpret=interpret,
         frontier_dtype=frontier_dtype, per_vertex=bool(o.per_vertex),
     )
-    shard = jax.shard_map(
-        fn,
-        mesh=mesh,
-        in_specs=(P(axis_name), P(axis_name)),
-        out_specs=result_out_specs(axis_name, per_vertex=bool(o.per_vertex)),
-    )
-    sharding = NamedSharding(mesh, P(axis_name))
-    s_dev = jax.device_put(jnp.asarray(s_sh.reshape(-1)), sharding)
-    d_dev = jax.device_put(jnp.asarray(d_sh.reshape(-1)), sharding)
-    return jax.jit(shard)(s_dev, d_dev)
+    with obs.span("tc.probe"):
+        res = _tc_distributed(s_dev, d_dev, mesh=mesh,
+                              body=tuple(sorted(fn.keywords.items())))
+    return res, hplan
 
 
 def parallel_triangle_count(

@@ -9,12 +9,16 @@
   ``tc.plan_sync`` (the pooled-profile ``device_get``), ``tc.plan_layout``
   (``plan_buckets``), ``tc.probe`` (the probe program's dispatch) and
   ``tc.fetch`` (the result ``device_get``); the server records
-  ``serve.flush`` and ``serve.finalize``.
+  ``serve.flush`` and ``serve.finalize``.  The distributed route
+  (Algorithm 2) records ``tc.ingest``, ``tc.shard`` (``shard_edges``
+  and the uploads of the shards), ``tc.plan_layout``
+  (``plan_hedge_rounds``), ``tc.probe`` (the dispatch of
+  ``_tc_distributed``) and ``tc.fetch``.
 * A process-wide counter registry: :func:`incr` adds host-side numbers
   the caller already holds, :func:`counters` is a snapshot, and
-  :func:`reset` zeroes it.  The counters, both added by exact plans of
-  one lane (a batch's pooled profile bounds its lanes, it does not
-  give each lane's degrees):
+  :func:`reset` zeroes it.  The counters of the local route, both added
+  by exact plans of one lane (a batch's pooled profile bounds its
+  lanes, it does not give each lane's degrees):
 
   ``probe.entries_gathered``
       list entries the plan's dense gathers read: the plan's
@@ -22,6 +26,18 @@
       target lists (Pallas), ``rows × d_targ`` targets;
   ``probe.entries_real``
       the real neighbour ids among them (the planned rows' degrees).
+
+  And of the distributed route, added by each count's report:
+
+  ``dist.counts``
+      distributed counts made;
+  ``dist.rows_planned``
+      rows the devices probed: p × the horizontal-round plan's rows,
+      p times that again in ring mode (p rounds);
+  ``dist.rows_real``
+      p × the horizontal edges: every device probes each once;
+  ``dist.wire_bytes``
+      the run's ``CommTally`` over all phases, with its BFS sweeps.
 
 There is no exporter: the profiler, once someone starts it, writes the
 spans, and :func:`counters` is the scrape.

@@ -80,3 +80,17 @@ FIXTURES = {
 def named_graph(request):
     edges, n = FIXTURES[request.param]
     return request.param, edges, n, from_edges(edges, n)
+
+
+#: test sizes of the benchmark drivers that ``tests/bench``'s table of
+#: sizes (``test_bench_control.SMALL``) does not list: the cells over a
+#: mesh run there too, at this size, on the devices the process has
+BENCH_DRIVER_SIZES = {"count_mesh": {"scale": 9}}
+
+
+@pytest.fixture(autouse=True)
+def _bench_driver_sizes(request):
+    small = getattr(request.module, "SMALL", None)
+    if isinstance(small, dict):
+        for driver, size in BENCH_DRIVER_SIZES.items():
+            small.setdefault(driver, size)
