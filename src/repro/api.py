@@ -1030,6 +1030,7 @@ class TriangleEngine:
         rounds = p if o.mode == "ring" else 1
         obs.incr("dist.counts")
         obs.incr("dist.rows_planned", p * rounds * plan.probe_rows)
+        obs.incr("dist.entries_planned", rounds * plan.gather_entries)
         obs.incr("dist.rows_real", p * int(nh))
         obs.incr("dist.wire_bytes", comm.total)
         pv = degs = None

@@ -74,53 +74,40 @@ def horizontal_queries(g, level, *, order: str = "asc"):
     return qu, qw, d_small, d_large, n_h
 
 
-def mindeg_per_slot(src, dst, deg):
-    """Host-side ``(und, mind)`` per edge slot: ``und`` marks the
-    undirected (``src < dst``) slots — sentinel pads have ``src == dst``
-    and drop out — and ``mind`` their smaller endpoint's degree (0
-    elsewhere).  Accepts any slot layout (flat edge list or per-shard
-    2-D), preserving the shape.
+def degree_exceedance(src, dst, deg, *, widths=(), edges=()):
+    """Host-side degree-histogram bounds for ``plan_buckets_bounded``,
+    over blocks of edge slots: ``src``/``dst`` are ``[blocks, slots]``
+    (the whole edge list as one block, or one row a shard).  A slot
+    counts if it holds an undirected edge once (``src < dst``; sentinel
+    pads have ``src == dst`` and drop out).  Returns ``(small, large)``:
+    for each width ``w``, the most counted slots of any one block whose
+    smaller endpoint has degree > ``w``; for each edge ``e``, the most
+    whose larger endpoint has degree > ``e`` (at ``e = 0``: the block's
+    undirected edges).
 
-    This is the ONE place the bucket planners' exceedance semantics are
-    encoded; every bound they consume counts ``mind > w`` strictly (a
-    query with d_small == w fits a w-wide bucket), so keep callers and
-    this helper in lockstep.
+    The horizontal queries of *any* BFS are a subset of these edges, so
+    the counts bound every block's rows no matter which root Algorithm 2
+    runs from, before the BFS has happened (DESIGN.md §3).  This is the
+    ONE place the planners' exceedance semantics are encoded: every bound
+    counts degrees strictly above the width (a query with d_small == w
+    fits a w-wide bucket, one with d_large == e a band that ends at e).
     """
     import numpy as np
 
+    src, dst, deg = (np.asarray(x) for x in (src, dst, deg))
     und = src < dst
-    if deg.shape[0] == 0:
-        return und, np.zeros_like(src)
-    hi = deg.shape[0] - 1
-    mind = np.where(
-        und,
-        np.minimum(deg[np.clip(src, 0, hi)], deg[np.clip(dst, 0, hi)]),
-        0,
-    )
-    return und, mind
+    if deg.shape[0]:
+        du, dw = (deg[np.clip(x, 0, deg.shape[0] - 1)] for x in (src, dst))
+    else:
+        du = dw = np.zeros_like(src)
+    mind = np.where(und, np.minimum(du, dw), 0)
+    maxd = np.where(und, np.maximum(du, dw), 0)
 
+    def most(d, t):
+        return int((d > int(t)).sum(axis=-1).max(initial=0))
 
-def mindeg_exceedance(g, widths) -> tuple[int, ...]:
-    """Host-side degree histogram bound for the planned-bucket engine:
-    for each width ``w``, the number of undirected edges whose smaller
-    endpoint has degree > ``w``.
-
-    The horizontal queries of *any* BFS are a subset of the undirected
-    edges, so these counts upper-bound every bucket's occupancy no matter
-    which root Algorithm 2 runs from — which is what lets
-    ``plan_buckets_bounded`` lay out static shard_map-safe bucket rows
-    before the BFS has happened (DESIGN.md §3).
-    """
-    import numpy as np
-
-    import jax
-
-    _, mind = mindeg_per_slot(
-        np.asarray(jax.device_get(g.src)),
-        np.asarray(jax.device_get(g.dst)),
-        np.asarray(jax.device_get(g.deg)),
-    )
-    return tuple(int((mind > int(w)).sum()) for w in widths)
+    return (tuple(most(mind, w) for w in widths),
+            tuple(most(maxd, e) for e in edges))
 
 
 def classify_edges(src, dst, level, n_nodes):

@@ -19,10 +19,11 @@ single engine in this module (DESIGN.md §2–§3):
   known as an upper bound — the shard_map case) both produce an
   ``IntersectPlan``: a tuple of contiguous query-row buckets, each with a
   static row count and candidate/target widths.  A plan is hashable and
-  jit-/shard_map-static.  An exact plan that gathers dense targets splits
-  a candidate bucket whose larger degrees straddle ``TARGET_BAND_EDGE``
+  jit-/shard_map-static.  A plan that gathers dense targets splits a
+  candidate bucket whose larger degrees straddle ``TARGET_BAND_EDGE``
   (or its ×4 multiples) into target-width bands, so rows next to a hub
-  no longer pay the hub's target width.
+  no longer pay the hub's target width: an exact plan from the degrees
+  it knows, a bounded one from exceedance bounds on them.
 
 * **Execution.**  ``run_plan`` slices the (degree-sorted) query block at
   the plan's static boundaries and probes each bucket at its own padded
@@ -185,6 +186,16 @@ class IntersectPlan:
         return float(sum(float(b.rows) * b.d_cand for b in self.buckets))
 
     @property
+    def gather_entries(self) -> int:
+        """List entries the plan's dense gathers read: ``rows × d_cand``
+        candidates a bucket, plus ``rows × d_targ`` targets where the
+        backend compares against dense target lists (not ``"jnp"``,
+        which searches the adjacency)."""
+        targ = self.backend != "jnp"
+        return sum(b.rows * (b.d_cand + (b.d_targ if targ else 0))
+                   for b in self.buckets)
+
+    @property
     def peak_rows(self) -> int:
         return max(
             (min(b.rows, self.query_chunk or b.rows) for b in self.buckets),
@@ -306,11 +317,24 @@ def _target_bands(dl) -> list[tuple[int, int]]:
     ]
 
 
+def target_band_edges(top: int) -> tuple[int, ...]:
+    """The target-band edges below ``top``, ascending: ``TARGET_BAND_EDGE``
+    and its ×4 multiples.  Each is the lower edge of a band; the
+    narrowest band lies below the first."""
+    edges = []
+    e = TARGET_BAND_EDGE
+    while e < top:
+        edges.append(e)
+        e *= 4
+    return tuple(edges)
+
+
 def plan_buckets_bounded(
     total_rows: int,
     *,
     d_pad: int,
     exceed: tuple[tuple[int, int], ...] | None = None,
+    exceed_large: tuple[tuple[int, int], ...] | None = None,
     bucket_widths: tuple[int, ...] = DEFAULT_BUCKET_WIDTHS,
     row_mult: int = 1,
     backend: str = "jnp",
@@ -325,15 +349,16 @@ def plan_buckets_bounded(
     degree histogram instead (``core.sequential.batch_plan_for``).
 
     ``sort_queries=None`` (default) lets ``run_plan`` degree-sort each
-    block in-trace whenever the plan has more than one bucket; pass
-    ``False`` when the caller's query blocks are already laid out
-    descending by min-degree (``horizontal_queries(order="desc")``), so
-    the executor skips the second argsort.
+    block in-trace whenever the plan has more than one candidate bucket
+    or ends before the block; pass ``False`` when the caller's query
+    blocks are already laid out descending by min-degree
+    (``horizontal_queries(order="desc")``), so the executor skips the
+    second argsort.
 
     ``exceed`` is a tuple of ``(width, bound)`` pairs: for each candidate
     bucket width, an upper bound on how many queries of *any* block this
     plan will run can have min-endpoint degree above that width (e.g.
-    ``core.edges.mindeg_exceedance`` — the whole graph's histogram bounds
+    ``core.edges.degree_exceedance`` — the whole graph's histogram bounds
     every BFS's horizontal subset).  Buckets are laid out widest-first
     and sized from those bounds, and ``run_plan`` sorts the block by
     descending min-degree (``sort_queries=True``), so by construction
@@ -343,12 +368,38 @@ def plan_buckets_bounded(
     the max degree) the run flags ``overflow`` instead of miscounting
     silently.  ``exceed=None`` degenerates to one ``d_pad``-wide bucket —
     always safe, no host knowledge needed (the dry-run path).
+
+    ``exceed_large`` is the same kind of table for the *larger* endpoint:
+    ``(edge, bound)`` pairs, each bounding how many queries of any block
+    can have their larger list longer than ``edge``.  It sizes the plan
+    in its other dimension, as ``plan_buckets`` does from a known
+    profile.  A bound at edge 0 bounds the queries that can hold a
+    common neighbour at all, so the plan ends there, rounded to
+    ``row_mult``: the in-trace sort ranks them first, and the rows past
+    the plan are never probed.  Where the backend gathers dense targets
+    (not ``"jnp"``), the bounds at ``target_band_edges(d_pad)`` split
+    each candidate bucket into target bands, widest first: the widest at
+    ``d_targ = d_pad``, each one below at ``d_targ`` equal to its upper
+    edge, ``d_cand = min(width, d_targ)``, and rows such that the
+    bucket's rows up to the band's end cover ``min(bucket rows, bound at
+    its lower edge)``.  Only that minimum is safe: a query whose smaller
+    list is short may still have a long larger list, so a bucket's rows
+    are bounded by the bucket and by the whole table, not by its width.
+    ``band_ends`` then tells ``run_plan`` to sort each bucket's rows by
+    larger list, descending.  A violated bound flags ``overflow`` as
+    above.  Without ``exceed_large`` the plan is the one above.
     """
-    T = _ceil_to(int(total_rows), row_mult) if total_rows > 0 else 0
+    def rows_for(n):  # whole row_mult steps that hold n rows
+        return _ceil_to(int(n), row_mult) if n > 0 else 0
+
+    T = rows_for(total_rows)
+    large = dict(exceed_large or ())
+    if 0 in large:
+        T = min(T, rows_for(large[0]))
     if T == 0:
         return IntersectPlan((), backend, interpret, query_chunk, False)
     if sort_queries is None:
-        sort_queries = True  # resolved to len(buckets) > 1 below
+        sort_queries = True  # resolved below
     top = int(d_pad)
     bound = dict(exceed or ())
     widths = sorted(
@@ -356,7 +407,7 @@ def plan_buckets_bounded(
         if 0 < w < top and w in bound
     )
     widths.append(top)  # ascending, widest last
-    buckets = []
+    segments = []  # (start, rows, width) of each candidate bucket
     used = 0
     for i in range(len(widths) - 1, -1, -1):  # allocate widest-first
         w = widths[i]
@@ -365,19 +416,45 @@ def plan_buckets_bounded(
         else:
             # every query with min-degree > widths[i-1] must rank before
             # this bucket's end — size it so cumulative rows cover the bound
-            need = int(bound[widths[i - 1]])
-            need_rows = _ceil_to(need, row_mult) if need > 0 else 0
+            need_rows = rows_for(bound[widths[i - 1]])
             rows = min(T - used, max(0, need_rows - used))
         if rows <= 0:
             continue
-        buckets.append(PlanBucket(
-            start=used, count=rows, rows=rows, d_cand=w, d_targ=top,
-        ))
+        segments.append((used, rows, w))
         used += rows
+    # target bands widest first, as (lower edge, d_targ); the narrowest
+    # band (lower edge None) takes what is left of its bucket
+    lower = ([] if backend == "jnp"
+             else [e for e in target_band_edges(top) if e in large])
+    bands = list(zip(lower[::-1], [top] + lower[:0:-1])) + [
+        (None, lower[0] if lower else top)
+    ]
+    buckets = []
+    banded = False
+    for start, rows, w in segments:
+        band_rows = 0
+        emitted = 0
+        for edge, d_targ in bands:
+            if edge is None:
+                r = rows - band_rows
+            else:
+                r = min(rows, rows_for(large[edge])) - band_rows
+            if r <= 0:
+                continue
+            buckets.append(PlanBucket(
+                start=start + band_rows, count=r, rows=r,
+                d_cand=min(w, d_targ), d_targ=d_targ,
+            ))
+            band_rows += r
+            emitted += 1
+        banded |= emitted > 1
     return IntersectPlan(
         buckets=tuple(buckets), backend=backend, interpret=interpret,
         query_chunk=query_chunk,
-        sort_queries=bool(sort_queries) and len(buckets) > 1,
+        sort_queries=bool(sort_queries)
+        and (len(segments) > 1 or T < int(total_rows)),
+        band_ends=(tuple(start + rows for start, rows, _ in segments)
+                   if banded else ()),
     )
 
 

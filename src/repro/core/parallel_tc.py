@@ -27,11 +27,12 @@ counted exactly once (no /3 here — that dedup is the point of N-hat).
 All shapes are static; the two data-dependent capacities carry overflow
 flags (regular sampling bounds any receiver at 2x the average — the flags
 make the bound *checked* instead of assumed).  The intersection plan is
-likewise static: ``plan_hedge_rounds`` sizes its degree buckets on the
-host from the graph's degree histogram (an upper bound valid for any
-BFS), and ``run_plan`` degree-sorts each gathered round in-trace so every
-query provably fits its bucket — bucket-width mis-fits flag overflow
-instead of miscounting.
+likewise static: ``plan_hedge_rounds`` sizes its degree buckets, their
+target bands and its total rows on the host from the graph's degree
+histogram (an upper bound valid for any BFS), and ``run_plan``
+degree-sorts each gathered round in-trace so every query provably fits
+its bucket — width mis-fits, and more horizontal edges than a ring plan
+has rows, flag overflow instead of miscounting.
 """
 from __future__ import annotations
 
@@ -46,7 +47,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import obs
 from repro.core.bfs import UNVISITED, bfs_levels
 from repro.core.comm_instrument import CommTally, tally_comm
-from repro.core.edges import horizontal_mask, mindeg_exceedance
+from repro.core.edges import degree_exceedance, horizontal_mask
 from repro.core.intersect import (
     DEFAULT_BUCKET_WIDTHS,
     IntersectPlan,
@@ -54,9 +55,10 @@ from repro.core.intersect import (
     plan_buckets_bounded,
     resolve_backend,
     run_plan,
+    target_band_edges,
 )
 from repro.core.sampling import repartition_by_value
-from repro.graph.csr import Graph, max_degree
+from repro.graph.csr import Graph, _ceil_to, max_degree
 from repro.graph.partition import shard_edges
 
 
@@ -132,29 +134,16 @@ def _hedge_layout(
     return rows, chunk
 
 
-def _ring_mindeg_exceedance(
-    g: Graph, p: int, widths, shards=None
-) -> tuple[int, ...]:
-    """Ring-mode bucket bound: one shared plan serves every device's
-    cap_hedge block, so each width's cap is the max over shards of that
-    shard's undirected edges above the width.  ``shard_edges`` is
-    deterministic and host-side, so this is static — and per-shard bounds
-    are ~p× tighter than the whole-graph histogram, which would otherwise
-    swallow the narrow buckets whenever cap_hedge < exceed(w).
-    ``shards``: optional pre-sharded ``(src[p, cap], dst[p, cap])``
-    (``parallel_triangle_count`` passes its own to avoid sharding twice);
-    the planner only reads edge content, so any capacity works."""
-    import numpy as np
-
-    from repro.core.edges import mindeg_per_slot
-
-    if shards is None:
-        shards = shard_edges(g, p, capacity=None)[:2]
-    s_sh, d_sh = shards
-    _, mind = mindeg_per_slot(s_sh, d_sh, np.asarray(jax.device_get(g.deg)))
-    return tuple(
-        int((mind > int(w)).sum(axis=1).max(initial=0)) for w in widths
-    )
+def _least_plan_rows(m2: int, p: int, mode: str,
+                     hedge_chunk: int | None) -> int:
+    """The fewest rows a horizontal-round plan of ``plan_hedge_rounds``
+    can end at: every undirected edge can be horizontal, and the
+    gathered block holds each once; a ring block holds one shard's, and
+    the fullest shard holds at least ``ceil(m / p)``."""
+    rows, chunk = _hedge_layout(m2, p, mode, hedge_chunk)
+    m = m2 // 2
+    real = m if mode == "allgather" else -(-m // p)
+    return min(rows, _ceil_to(real, chunk)) if real else 0
 
 
 def plan_hedge_rounds(
@@ -174,15 +163,28 @@ def plan_hedge_rounds(
     One query block per round: the full gathered horizontal edge set
     (``allgather`` mode — p·cap_hedge rows, executed once) or one
     device's shard (``ring`` mode — cap_hedge rows, executed p times).
-    Bucket caps come from degree-histogram exceedance bounds — any BFS's
-    horizontal subset is bounded by the edges present (whole graph for
-    the gathered block, per-shard max for ring blocks) — so the plan is
-    safe for whatever roots/levels the run produces.  ``hedge_chunk``
-    sets both the probe slice and the bucket-row granularity (small-
-    cap_hedge/high-p runs coarsen to whole-buffer buckets).  Exposed
-    publicly so benchmarks and examples can introspect exactly the
-    bucket layout the distributed path will execute.
+    Candidate buckets, target bands and the plan's last row come from
+    degree-histogram exceedance bounds (``degree_exceedance``): any
+    BFS's horizontal subset is bounded by the edges present — the whole
+    graph for the gathered block, the per-shard max for ring blocks,
+    ~p× tighter — so the plan is safe for whatever roots/levels the run
+    produces.  The bounds count global degrees, and a device's sublist
+    of a vertex is never longer than its degree, so every bound of the
+    form "rows whose list exceeds E" holds for the local lists the
+    devices probe.  The plan ends at the rows that can be real (at most
+    m undirected edges, each once; the fullest shard's in ring mode):
+    about half the gathered block, whose rows hold a device's buffer
+    padding too.  ``hedge_chunk`` sets both the probe slice and the
+    bucket-row granularity (small-cap_hedge/high-p runs coarsen to
+    whole-buffer buckets).  ``shards``: optional pre-sharded
+    ``(src[p, cap], dst[p, cap])`` for the ring bounds
+    (``parallel_triangle_count`` passes its own to avoid sharding twice);
+    the planner only reads edge content, so any capacity works.
+    Exposed publicly so benchmarks and examples can introspect exactly
+    the bucket layout the distributed path will execute.
     """
+    import numpy as np
+
     m2 = int(jax.device_get(g.n_edges_dir))
     if d_pad is None:
         d_pad = max(1, max_degree(g))
@@ -190,15 +192,22 @@ def plan_hedge_rounds(
     widths = tuple(sorted(
         w for w in {int(w) for w in bucket_widths} if 0 < w < d_pad
     ))
+    edges = (0,) + target_band_edges(d_pad)
     if mode == "ring":
-        bounds = _ring_mindeg_exceedance(g, p, widths, shards=shards)
+        if shards is None:
+            shards = shard_edges(g, p, capacity=None)[:2]
+        src, dst = shards
     else:
-        bounds = mindeg_exceedance(g, widths)
-    exceed = tuple(zip(widths, bounds))
+        src, dst = (np.asarray(x)[None]
+                    for x in jax.device_get((g.src, g.dst)))
+    small, large = degree_exceedance(
+        src, dst, jax.device_get(g.deg), widths=widths, edges=edges
+    )
     return plan_buckets_bounded(
         rows,
         d_pad=d_pad,
-        exceed=exceed,
+        exceed=tuple(zip(widths, small)),
+        exceed_large=tuple(zip(edges, large)),
         bucket_widths=widths,
         row_mult=chunk,
         backend=intersect_backend,
@@ -264,8 +273,13 @@ def _tc_shard(
         hv = jnp.where(is_h[order], src_i[order], inf)[:cap_hedge]
         hw = jnp.where(is_h[order], dst_i[order], inf)[:cap_hedge]
         n_h_local = jnp.sum(is_h, dtype=jnp.int32)
+        # a ring round probes one device's block, and a plan that ends
+        # before it skips the rows past its end (``build_tc_shard_fn``
+        # refuses a plan too short for the gathered block: m rows)
+        limit = (cap_hedge if mode == "allgather"
+                 else min(cap_hedge, hplan.total_rows))
         hedge_overflow = (
-            jax.lax.pmax((n_h_local > cap_hedge).astype(jnp.int32),
+            jax.lax.pmax((n_h_local > limit).astype(jnp.int32),
                          axis_name) > 0
         )
 
@@ -385,13 +399,15 @@ def build_tc_shard_fn(
             backend=intersect_backend, interpret=interpret,
             query_chunk=chunk,
         )
-    elif hplan.buckets and hplan.total_rows < rows:
-        # run_plan probes only plan.total_rows rows — an undersized plan
-        # (e.g. built for ring, used for allgather) would silently skip
-        # horizontal edges instead of flagging anything
+    elif hplan.total_rows < _least_plan_rows(m2, p, mode, hedge_chunk):
+        # run_plan probes only plan.total_rows rows — a plan shorter than
+        # the rows that can be real (e.g. built for ring, used for
+        # allgather) is refused here; the shard flags the rest at run time
         raise ValueError(
             f"hplan covers {hplan.total_rows} rows but mode={mode!r} "
-            f"probes {rows}-row blocks (plan_hedge_rounds mode mismatch?)"
+            f"blocks of {rows} rows can hold "
+            f"{_least_plan_rows(m2, p, mode, hedge_chunk)} real ones "
+            f"(plan_hedge_rounds mode mismatch?)"
         )
     fn = functools.partial(
         _tc_shard, n=n, p=p, root=root, cap_chunk=cap_chunk,

@@ -36,6 +36,10 @@
       p times that again in ring mode (p rounds);
   ``dist.rows_real``
       p × the horizontal edges: every device probes each once;
+  ``dist.entries_planned``
+      list entries one device's dense gathers read: the plan's
+      ``IntersectPlan.gather_entries``, p times that in ring mode
+      (every device gathers as much);
   ``dist.wire_bytes``
       the run's ``CommTally`` over all phases, with its BFS sweeps.
 

@@ -70,7 +70,8 @@ for name, (edges, n) in graphs.items():
     t1 = len(traces)
     again = engine.count((edges, n), route="distributed")
     c = obs.counters()
-    plan = ptc.plan_hedge_rounds(from_edges(edges, n), 4)
+    g = from_edges(edges, n)
+    plan = ptc.plan_hedge_rounds(g, 4)
     out[name] = {
         "oracle": int(oracle.triangle_counts(edges, n).sum()) // 3,
         "local": engine.count((edges, n), route="local").triangles,
@@ -79,6 +80,8 @@ for name, (edges, n) in graphs.items():
         "overflow": bool(rep.overflow), "plan_id": rep.plan_id,
         "traces_first": t1 - t0, "traces_second": len(traces) - t1,
         "counters": c, "plan_rows": plan.probe_rows,
+        "plan_entries": plan.gather_entries,
+        "m": int(g.n_edges_dir) // 2, "plan_chunk": plan.query_chunk,
         "num_horizontal": rep.num_horizontal, "wire": rep.comm.total,
     }
 edges, n = graphs["kron9"]
@@ -90,6 +93,8 @@ out["ring"] = {
     "counters": obs.counters(), "num_horizontal": ring.num_horizontal,
     "plan_rows": ptc.plan_hedge_rounds(from_edges(edges, n), 4,
                                        mode="ring").probe_rows,
+    "plan_entries": ptc.plan_hedge_rounds(from_edges(edges, n), 4,
+                                          mode="ring").gather_entries,
 }
 print("RESULT", json.dumps(out))
 """
@@ -131,9 +136,12 @@ def test_dist_counters_hold_plan_and_report(counts, name):
         "dist.counts": 2,
         "dist.rows_planned": 2 * 4 * c["plan_rows"],
         "dist.rows_real": 2 * 4 * c["num_horizontal"],
+        "dist.entries_planned": 2 * c["plan_entries"],
         "dist.wire_bytes": 2 * c["wire"],
     }
-    assert 0 < c["num_horizontal"] <= c["plan_rows"]
+    # the plan ends at the first chunk past the m rows that can be real
+    assert 0 < c["num_horizontal"] <= c["m"] <= c["plan_rows"]
+    assert c["plan_rows"] < c["m"] + c["plan_chunk"]
 
 
 def test_ring_mode_counts_p_rounds_of_rows(counts):
@@ -142,6 +150,7 @@ def test_ring_mode_counts_p_rounds_of_rows(counts):
     assert r["plan_id"] == "hedge/ring/p4"
     assert r["counters"]["dist.rows_planned"] == 4 * 4 * r["plan_rows"]
     assert r["counters"]["dist.rows_real"] == 4 * r["num_horizontal"]
+    assert r["counters"]["dist.entries_planned"] == 4 * r["plan_entries"]
     # the same horizontal-edge volume crosses the wire in either mode
     assert r["counters"]["dist.wire_bytes"] == counts["kron9"]["wire"]
 
