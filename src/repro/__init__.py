@@ -1,9 +1,9 @@
-"""repro — cover-edge triangle counting (Bader et al., cs.DC 2022) as a
-multi-pod JAX framework.  See README.md / DESIGN.md / EXPERIMENTS.md.
+"""repro — cover-edge triangle counting (Bader et al., cs.DC 2022) in
+JAX, on one device or a mesh.  See README.md / DESIGN.md.
 
 The public front door is :mod:`repro.api` — re-exported lazily here
 (``repro.TriangleEngine`` etc.) so that importing the bare package stays
-free of jax side effects (``launch.dryrun`` must set ``XLA_FLAGS``
+free of jax side effects (a script may still set ``XLA_FLAGS`` after it,
 before the first jax import).
 """
 

@@ -117,6 +117,16 @@ _SUBCALL = frozenset({
 })
 
 
+# newer JAX (0.9 among them) names the call primitive ``jit``, older
+# JAX ``pjit``; sites keep ``pjit`` so baselines stay keyed alike
+_PRIM_ALIASES = {"jit": "pjit"}
+
+
+def _prim(eqn) -> str:
+    name = eqn.primitive.name
+    return _PRIM_ALIASES.get(name, name)
+
+
 class _Propagator:
     """One program's interval walk.  Collects overflow sites keyed by
     ``(program_label, kind, primitive)``."""
@@ -192,11 +202,11 @@ class _Propagator:
             outs = self._eval(eqn, ins)
             for v, b in zip(eqn.outvars, outs):
                 write(v, b)
-                self._check(v, b, "op", eqn.primitive.name)
+                self._check(v, b, "op", _prim(eqn))
         return [read(v) for v in jaxpr.outvars]
 
     def _eval(self, eqn, ins: list[Bound]) -> list[Bound]:
-        name = eqn.primitive.name
+        name = _prim(eqn)
         n_out = len(eqn.outvars)
         top = [None] * n_out
 

@@ -580,8 +580,13 @@ class TriangleEngine:
         """The engine-owned bounded-plan cache, keyed on
         ``(budget, meta, options.plan_view())`` — the options resolved
         per cell (``options_for``)."""
+        return self._plan(gb, None)
+
+    def _plan(self, gb: GraphBatch, options: Optional[TCOptions]):
+        """``plan_for``, but a call that gives its own options plans
+        with them."""
         return _seq.batch_plan_for(
-            gb, options=self.options_for(gb.budget),
+            gb, options=options or self.options_for(gb.budget),
             cache=self._plan_cache, stats=self._plan_stats,
         )
 
@@ -789,7 +794,7 @@ class TriangleEngine:
                     [_host_edges(g) if is_graph else (edges, n_nodes)],
                     grid=self.budgets,
                 )
-            plan = self.plan_for(gb)
+            plan = self._plan(gb, options)
             res = self.count_batch_raw(gb, options=o, plan=plan)
             res = _seq._squeeze_lane(res)
             # the lane is budget-padded: slice attribution (and degrees)
@@ -844,7 +849,7 @@ class TriangleEngine:
         can_plan = (gb.meta is not None and o.d_max is None
                     and o.cap_h is None)
         if can_plan:
-            plan = self.plan_for(gb)
+            plan = self._plan(gb, options)
         res = self.count_batch_raw(gb, options=o, plan=plan)
         backend, _ = resolve_backend(o.backend, o.interpret)
         pid = (_plan_id(plan, "bounded") if plan is not None

@@ -82,11 +82,11 @@ def result_out_specs(axis_name: str = "p", per_vertex: bool = False):
     """``shard_map`` out_specs pytree for ``_tc_shard``'s result —
     per-device fields sharded over ``axis_name``, everything else
     (scalars + the comm tally) replicated.  The ONE definition shared
-    by ``parallel_triangle_count``, the dry-run registry and the comm
-    instrument, so adding a result field cannot silently desynchronize
-    them.  ``per_vertex`` must match the shard fn's flag: the spec
-    pytree has to mirror the result's (``None`` when attribution is
-    off, a replicated vector — it is psummed in the body — when on)."""
+    by ``parallel_triangle_count`` and the comm instrument, so adding a
+    result field cannot silently desynchronize them.  ``per_vertex``
+    must match the shard fn's flag: the spec pytree has to mirror the
+    result's (``None`` when attribution is off, a replicated vector —
+    it is psummed in the body — when on)."""
     rep = P()
     return ParallelTCResult(
         triangles=rep,

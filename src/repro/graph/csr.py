@@ -262,10 +262,21 @@ class BudgetGrid:
             )
 
     def _round(self, x: int, lo: int) -> int:
+        """The smallest cell ``ceil(lo * factor**k)`` holding ``x``.  The
+        log only guesses ``k``; the walk settles it on the integer cells
+        themselves, so every cell rounds onto itself."""
         if x <= lo:
             return lo
-        k = math.ceil(math.log(x / lo) / math.log(self.factor) - 1e-9)
-        return int(math.ceil(lo * self.factor ** k))
+
+        def cell(k: int) -> int:
+            return int(math.ceil(lo * self.factor ** k))
+
+        k = max(0, math.ceil(math.log(x / lo) / math.log(self.factor)))
+        while k > 0 and cell(k - 1) >= x:
+            k -= 1
+        while cell(k) < x:
+            k += 1
+        return cell(k)
 
     def _cell(self, n_nodes: int, n_edges_und: int) -> ShapeBudget:
         return ShapeBudget(

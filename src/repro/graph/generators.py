@@ -144,19 +144,3 @@ def dolphins_like(seed: int = 7) -> tuple[np.ndarray, int]:
     extra = rng.integers(0, n, size=(60, 2))
     edges += [tuple(e) for e in extra if e[0] != e[1]]
     return np.asarray(edges, dtype=np.int64), n
-
-
-def random_geometric(n: int, radius: float, *, seed: int = 0) -> tuple[np.ndarray, int]:
-    """Points in the unit cube joined under ``radius`` — molecule-style
-    fixture for SchNet/DimeNet (positions regenerated by the caller with the
-    same seed)."""
-    rng = np.random.default_rng(seed)
-    pos = rng.random((n, 3))
-    d2 = ((pos[:, None, :] - pos[None, :, :]) ** 2).sum(-1)
-    i, j = np.nonzero(np.triu(d2 < radius * radius, k=1))
-    return np.stack([i, j], axis=1).astype(np.int64), n
-
-
-def positions_for(n: int, *, seed: int = 0) -> np.ndarray:
-    rng = np.random.default_rng(seed)
-    return rng.random((n, 3)).astype(np.float32)

@@ -1,19 +1,14 @@
-"""Segment-op substrate shared by the paper's algorithm, the GNNs and recsys.
+"""The segment reduction the triangle engine scatters credit with.
 
-JAX has no native EmbeddingBag / CSR SpMM; message passing and ragged
-reductions are built on ``jax.ops.segment_*`` over an edge index.  These
-wrappers pin the conventions used framework-wide:
-
-  * ``num_segments`` is always static,
-  * sentinel indices (``>= num_segments``) are dropped by JAX's segment ops
-    natively (out-of-range ids contribute nothing), which is how padded
-    edges/bags are ignored,
-  * ``segment_softmax`` is the GAT edge-softmax primitive.
+``num_segments`` is always static.  Ids outside ``[0, num_segments)``
+are dropped: the padded-edge sentinel ``num_segments`` and negative pads
+such as the intersection engine's ``CAND_PAD`` contribute nothing, which
+is how padded rows stay out of the per-vertex credit
+(``core.intersect._chunk_credit``).
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 
 def segment_sum(data, segment_ids, num_segments: int):
@@ -26,66 +21,3 @@ def segment_sum(data, segment_ids, num_segments: int):
     triangle pipeline's per-vertex credit scatters rely on exactly this
     (``core.intersect._chunk_credit``)."""
     return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
-
-
-def segment_max(data, segment_ids, num_segments: int):
-    """Max per segment; empty segments hold the dtype's identity
-    (``-inf`` for floats, the minimum for ints).  Same sentinel
-    convention as ``segment_sum``."""
-    return jax.ops.segment_max(data, segment_ids, num_segments=num_segments)
-
-
-def segment_mean(data, segment_ids, num_segments: int):
-    """Mean per segment; **empty segments are exactly 0** (not the
-    historical ``0 / eps`` noise — the count is clamped at 1, which
-    changes nothing for non-empty segments since their count is >= 1).
-    Same sentinel convention as ``segment_sum``: out-of-range ids join
-    neither the sum nor the count."""
-    s = segment_sum(data, segment_ids, num_segments)
-    ones = jnp.ones(data.shape[:1], dtype=s.dtype)
-    cnt = segment_sum(ones, segment_ids, num_segments)
-    cnt = jnp.maximum(cnt, 1)
-    return s / cnt.reshape(cnt.shape + (1,) * (s.ndim - 1))
-
-
-def segment_softmax(scores, segment_ids, num_segments: int):
-    """Numerically-stable softmax over variable-length segments.
-
-    ``scores`` is per-edge (last dims arbitrary); normalization groups by
-    ``segment_ids``.  Padded edges must carry ``segment_ids >= num_segments``
-    AND ``scores = -inf`` is unnecessary: they are excluded from the
-    normalizer by the out-of-range drop, and the caller masks their output.
-    """
-    seg_max = segment_max(scores, segment_ids, num_segments)
-    seg_max = jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)
-    gathered = seg_max[jnp.clip(segment_ids, 0, num_segments - 1)]
-    exp = jnp.exp(scores - gathered)
-    denom = segment_sum(exp, segment_ids, num_segments)
-    denom = jnp.maximum(denom, 1e-9)
-    return exp / denom[jnp.clip(segment_ids, 0, num_segments - 1)]
-
-
-def embedding_bag(
-    table: jnp.ndarray,
-    indices: jnp.ndarray,
-    bag_ids: jnp.ndarray,
-    num_bags: int,
-    *,
-    mode: str = "sum",
-    weights: jnp.ndarray | None = None,
-):
-    """torch.nn.EmbeddingBag equivalent: ragged gather + segment reduce.
-
-    ``indices``/``bag_ids`` are flat multi-hot lookups; padded lookups use
-    ``bag_ids >= num_bags`` (dropped) or ``indices`` pointing at a zero row.
-    """
-    rows = jnp.take(table, jnp.clip(indices, 0, table.shape[0] - 1), axis=0)
-    if weights is not None:
-        rows = rows * weights[:, None]
-    if mode == "sum":
-        return segment_sum(rows, bag_ids, num_bags)
-    if mode == "mean":
-        return segment_mean(rows, bag_ids, num_bags)
-    if mode == "max":
-        return segment_max(rows, bag_ids, num_bags)
-    raise ValueError(f"unknown mode {mode!r}")

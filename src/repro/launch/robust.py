@@ -210,9 +210,12 @@ def run_chaos(
     submitted: list[int] = []
     for i, req in enumerate(trace):
         target = t0 + req.t / speed
+        # one pump between arrivals however late the replay runs, then
+        # more while it waits for the stamped time
+        server.pump()
         while (now := time.perf_counter()) < target:
-            server.pump()
             time.sleep(min(pump_interval_s, target - now))
+            server.pump()
         edges, n_nodes = (faults.mutate(i, req.edges, req.n_nodes)
                           if faults is not None
                           else (req.edges, req.n_nodes))

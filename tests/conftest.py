@@ -1,6 +1,6 @@
 """Shared fixtures. NOTE: no XLA_FLAGS manipulation here — smoke tests and
-benches must see the real single CPU device; only launch/dryrun.py (and the
-subprocess-based multi-device tests) request placeholder devices."""
+benches must see the real single CPU device; only the subprocess-based
+multi-device tests request placeholder devices."""
 from __future__ import annotations
 
 import numpy as np
@@ -65,6 +65,16 @@ def hub_graph(clique: int = 10, hubs=(600, 2500)) -> tuple[np.ndarray, int]:
     return np.asarray(edges, dtype=np.int64), nxt
 
 
+def random_geometric(n: int, radius: float, *, seed: int = 0):
+    """Points in the unit cube joined under ``radius``: dense local
+    clusters, many triangles, no hubs."""
+    rng = np.random.default_rng(seed)
+    pos = rng.random((n, 3))
+    d2 = ((pos[:, None, :] - pos[None, :, :]) ** 2).sum(-1)
+    i, j = np.nonzero(np.triu(d2 < radius * radius, k=1))
+    return np.stack([i, j], axis=1).astype(np.int64), n
+
+
 FIXTURES = {
     "karate": gen.karate(),
     "ring_of_cliques": gen.ring_of_cliques(5, 6),
@@ -72,7 +82,7 @@ FIXTURES = {
     "rmat8": gen.rmat(8, 8, seed=1),
     "complete9": gen.complete(9),
     "dolphins_like": gen.dolphins_like(),
-    "geometric": gen.random_geometric(80, 0.25, seed=2),
+    "geometric": random_geometric(80, 0.25, seed=2),
 }
 
 

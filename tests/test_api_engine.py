@@ -247,26 +247,40 @@ def test_parallel_wedge_baseline_agrees_with_engine():
 # ------------------------------------------------------------- TCOptions
 
 
-def test_tcoptions_validates_in_one_place():
-    for bad in (
-        dict(backend="cuda"),
-        dict(route="remote"),
-        dict(mode="broadcast"),
-        dict(frontier_dtype="int64"),
-        dict(query_chunk=0),
-        dict(d_max=-1),
-        dict(bucket_widths=(32, 0)),
-        dict(row_mult=0),
-        dict(slack=0.0),
-        dict(gather_buffer_limit_bytes=0),
-        dict(deadline_s=0.0),
-        dict(admission_tokens=0),
-        dict(approx_samples=0),
-        dict(distributed_timeout_s=-1.0),
-    ):
-        with pytest.raises(ValueError):
-            TCOptions(**bad)
-    # normalization: widths coerced to an int tuple, options hashable
+BAD_OPTIONS = [
+    (dict(backend="cuda"), ValueError),
+    (dict(route="remote"), ValueError),
+    (dict(mode="broadcast"), ValueError),
+    (dict(frontier_dtype="int64"), ValueError),
+    (dict(query_chunk=0), ValueError),
+    (dict(d_max=-1), ValueError),
+    (dict(cap_h=0), ValueError),
+    (dict(d_pad=0), ValueError),
+    (dict(hedge_chunk=-1), ValueError),
+    (dict(bucket_widths=(32, 0)), ValueError),
+    (dict(row_mult=0), ValueError),
+    (dict(slack=0.0), ValueError),
+    (dict(gather_buffer_limit_bytes=0), ValueError),
+    (dict(deadline_s=0.0), ValueError),
+    (dict(admission_tokens=0), ValueError),
+    (dict(approx_samples=0), ValueError),
+    (dict(distributed_timeout_s=-1.0), ValueError),
+    (dict(grid="x"), TypeError),
+]
+
+
+@pytest.mark.parametrize(
+    "bad,error", BAD_OPTIONS,
+    ids=[",".join(f"{k}={v}" for k, v in bad.items())
+         for bad, _ in BAD_OPTIONS],
+)
+def test_tcoptions_validates_in_one_place(bad, error):
+    with pytest.raises(error):
+        TCOptions(**bad)
+
+
+def test_tcoptions_normalizes_widths_and_hashes():
+    # widths coerced to an int tuple, options hashable
     o = TCOptions(bucket_widths=[np.int64(32), 256])
     assert o.bucket_widths == (32, 256)
     assert hash(o) == hash(TCOptions(bucket_widths=(32, 256)))

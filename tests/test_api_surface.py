@@ -65,7 +65,7 @@ def test_api_all_imports_and_signatures_match_golden():
 
 def test_package_lazy_reexports():
     """``repro.TriangleEngine`` et al. resolve lazily (no jax import at
-    bare-package import time — launch.dryrun depends on that)."""
+    bare-package import time, so a script can still set ``XLA_FLAGS``)."""
     import importlib
     import subprocess
     import sys

@@ -12,6 +12,7 @@ from repro.core import comm_model as cm
 from repro.core.comm_instrument import (
     choose_hedge_mode,
     hedge_round_buffer_bytes,
+    hlo_collective_counts,
     tally_comm,
 )
 from tests.test_parallel_tc import run_multidevice
@@ -80,6 +81,39 @@ def test_shard_fn_fallback_plan_respects_backend_knobs():
     assert fn.keywords["hplan"].backend == "pallas"
     assert fn.keywords["hplan"].interpret is True
     assert fn.keywords["frontier_dtype"] == "uint8"
+
+
+#: one StableHLO op of each collective kind, as ``Lowered.as_text()``
+#: prints them on two devices
+HLO_OPS = {
+    "all_gather": '%2 = "stablehlo.all_gather"(%1) <{all_gather_dim = 0 '
+                  ': i64, replica_groups = dense<[[0, 1]]> : tensor<1x2xi64>'
+                  '}> : (tensor<1x4xf32>) -> tensor<2x4xf32>',
+    "all_to_all": '%6 = "stablehlo.all_to_all"(%5) <{concat_dimension = 0 '
+                  ': i64, split_count = 2 : i64, split_dimension = 0 : i64}>'
+                  ' : (tensor<2x2xf32>) -> tensor<2x2xf32>',
+    "ppermute": '%4 = "stablehlo.collective_permute"(%arg1) <{'
+                'source_target_pairs = dense<[[0, 1], [1, 0]]> : '
+                'tensor<2x2xi64>}> : (tensor<4xf32>) -> tensor<4xf32>',
+    "all_reduce": '%3 = "stablehlo.all_reduce"(%arg1) <{replica_groups = '
+                  'dense<[[0, 1]]> : tensor<1x2xi64>}> ({',
+}
+#: not a collective, though its name shares a prefix with one
+HLO_LOCAL = ("%7 = stablehlo.reduce(%2 init: %cst) applies stablehlo.add "
+             "across dimensions = [0, 1] : (tensor<2x4xf32>, tensor<f32>) "
+             "-> tensor<f32>")
+
+
+@pytest.mark.parametrize("kind", sorted(HLO_OPS))
+def test_hlo_collective_counts_census(kind):
+    """Each of the four kinds counts once, the local reduce not at all,
+    and taking a kind's op out of the module zeroes that kind only."""
+    every = dict.fromkeys(HLO_OPS, 1)
+    assert hlo_collective_counts(
+        "\n".join([*HLO_OPS.values(), HLO_LOCAL])) == every
+    rest = [op for k, op in HLO_OPS.items() if k != kind]
+    assert hlo_collective_counts("\n".join([*rest, HLO_LOCAL])) == {
+        **every, kind: 0}
 
 
 @pytest.mark.slow

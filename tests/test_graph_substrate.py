@@ -1,7 +1,6 @@
-"""Graph container, partitioner, binary search, segment ops, sampler."""
+"""Graph container, partitioner, binary search."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,8 +11,6 @@ given, settings, st = optional_hypothesis()
 from repro.graph import generators as gen
 from repro.graph.csr import bounded_binary_search, from_edges, max_degree
 from repro.graph.partition import shard_edges, vertex_partition
-from repro.graph.sampler import sample_blocks
-from repro.graph.segment import embedding_bag, segment_mean, segment_softmax
 
 
 def test_csr_roundtrip_karate():
@@ -114,49 +111,6 @@ def test_shard_edges_covers_all_edges():
     want = set(zip(np.asarray(g.src)[: int(g.n_edges_dir)],
                    np.asarray(g.dst)[: int(g.n_edges_dir)]))
     assert got == want
-
-
-def test_segment_softmax_normalizes():
-    scores = jnp.asarray([0.1, 2.0, -1.0, 3.0, 0.0])
-    seg = jnp.asarray([0, 0, 1, 1, 5])  # last one dropped (out of range)
-    out = segment_softmax(scores, seg, 2)
-    np.testing.assert_allclose(float(out[0] + out[1]), 1.0, rtol=1e-6)
-    np.testing.assert_allclose(float(out[2] + out[3]), 1.0, rtol=1e-6)
-
-
-def test_segment_mean_and_embedding_bag():
-    table = jnp.arange(12, dtype=jnp.float32).reshape(6, 2)
-    idx = jnp.asarray([0, 1, 2, 5])
-    bags = jnp.asarray([0, 0, 1, 9])  # last dropped
-    out = embedding_bag(table, idx, bags, 2, mode="sum")
-    np.testing.assert_allclose(np.asarray(out[0]), [2.0, 4.0])
-    np.testing.assert_allclose(np.asarray(out[1]), [4.0, 5.0])
-    mean = segment_mean(table[idx], bags, 2)
-    np.testing.assert_allclose(np.asarray(mean[0]), [1.0, 2.0])
-
-
-def test_sampler_shapes_and_edges_valid():
-    edges, n = gen.rmat(8, 8, seed=2)
-    g = from_edges(edges, n)
-    seeds = jnp.arange(16, dtype=jnp.int32)
-    nodes, src_l, dst_l, seed_mask = sample_blocks(
-        jax.random.key(0), g.row_offsets, g.dst, g.deg, seeds, (5, 3), n
-    )
-    n_sub = 16 + 16 * 5 + 16 * 5 * 3
-    assert nodes.shape == (n_sub,)
-    assert src_l.shape == dst_l.shape == (16 * 5 + 16 * 5 * 3,)
-    assert int(seed_mask.sum()) == 16
-    # every non-padded sampled edge is a real graph edge
-    nodes_np, src_np, dst_np = map(np.asarray, (nodes, src_l, dst_l))
-    real = set(zip(np.asarray(g.src)[: int(g.n_edges_dir)],
-                   np.asarray(g.dst)[: int(g.n_edges_dir)]))
-    checked = 0
-    for s, d in zip(src_np, dst_np):
-        if d < n_sub and nodes_np[s] < n and nodes_np[d] < n:
-            # sampled edge goes child(s) -> parent(d); graph edge is (parent, child)
-            assert (int(nodes_np[d]), int(nodes_np[s])) in real
-            checked += 1
-    assert checked > 0
 
 
 def test_rmat_rejects_invalid_probabilities():

@@ -15,13 +15,14 @@ import textwrap
 import numpy as np
 import pytest
 
+from repro.graph import generators as gen
+
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def run_multidevice(body: str, ndev: int = 8) -> str:
     env = dict(os.environ)
-    # SET, not prepend: an inherited device-count flag (e.g. from an
-    # earlier import of repro.launch.dryrun in this process) would win
+    # SET, not prepend: an inherited device-count flag would win
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(
@@ -115,3 +116,52 @@ def test_parallel_single_device_degenerate():
     res = parallel_triangle_count(g, mesh)
     assert int(res.triangles) == 45
     assert not bool(res.transpose_overflow)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("graph", [
+    "gen.rmat(8, 8, seed=1)",
+    "gen.erdos_renyi(160, 0.06, seed=4)",
+], ids=["rmat8", "er"])
+def test_tc_uint8_frontier_and_tuned_knobs(graph):
+    out = run_multidevice(
+        f"""
+        import jax, numpy as np, networkx as nx
+        from jax.sharding import Mesh
+        from repro.graph import generators as gen
+        from repro.graph.csr import from_edges
+        from repro.core.parallel_tc import parallel_triangle_count
+
+        mesh = Mesh(np.array(jax.devices()).reshape(8), ('p',))
+        edges, n = {graph}
+        g = from_edges(edges, n)
+        G = nx.Graph(); G.add_nodes_from(range(n))
+        G.add_edges_from(np.asarray(edges))
+        G.remove_edges_from(nx.selfloop_edges(G))
+        want = sum(nx.triangles(G).values()) // 3
+        # tuned slack is exact; d_pad guard trips-or-matches
+        res = parallel_triangle_count(g, mesh, mode='ring', slack=2.0)
+        assert int(res.triangles) == want
+        assert not bool(res.transpose_overflow)
+        res64 = parallel_triangle_count(g, mesh, mode='ring', d_pad=16)
+        assert bool(res64.transpose_overflow) or int(res64.triangles) == want
+        print("TC_VARIANTS_OK")
+        """
+    )
+    assert "TC_VARIANTS_OK" in out
+
+
+@pytest.mark.parametrize("graph", [
+    lambda: gen.karate(),
+    lambda: gen.rmat(7, 6, seed=2),  # 22 isolated vertices: BFS reseeds
+], ids=["karate", "rmat7"])
+def test_uint8_frontier_levels_match_single_device(graph):
+    from repro.core.bfs import bfs_levels
+    from repro.graph.csr import from_edges
+
+    edges, n = graph()
+    g = from_edges(edges, n)
+    a = bfs_levels(g.src, g.dst, n)
+    # frontier_dtype only matters with an axis; single-device sanity:
+    b = bfs_levels(g.src, g.dst, n, frontier_dtype="uint8")
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -233,30 +233,6 @@ def test_approx_route_has_no_per_vertex():
     assert rep.route == "approx" and rep.per_vertex is None
 
 
-# ------------------------------------------------------------ example smoke
-def test_example_triangle_features_smoke():
-    """CI smoke for examples/gnn_cora.py's feature builder: finite,
-    non-negative, and the triangle column is log1p of the oracle counts."""
-    import importlib.util
-    import os
-
-    path = os.path.join(
-        os.path.dirname(__file__), "..", "examples", "gnn_cora.py"
-    )
-    spec = importlib.util.spec_from_file_location("gnn_cora_example", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    edges, n = FIXTURES["karate"]
-    feats = np.asarray(mod.triangle_features(np.asarray(edges), n))
-    assert feats.shape == (n, 2)
-    assert np.isfinite(feats).all() and (feats >= 0).all()
-    np.testing.assert_allclose(
-        feats[:, 1],
-        np.log1p(oracle.triangle_counts(edges, n).astype(np.float64)),
-        rtol=1e-6,
-    )
-
-
 # ------------------------------------------------------- distributed route
 @pytest.mark.slow
 def test_distributed_matches_oracle_over_device_counts():

@@ -1,19 +1,11 @@
 """Unit backfill for ``repro.graph.segment`` — the sentinel-drop
-convention every ragged reduction in the framework (and the per-vertex
-credit scatter) depends on, plus the empty-segment contracts."""
+convention the per-vertex credit scatter depends on."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from repro.graph.segment import (
-    embedding_bag,
-    segment_max,
-    segment_mean,
-    segment_softmax,
-    segment_sum,
-)
+from repro.graph.segment import segment_sum
 
 
 # ------------------------------------------------------------- segment_sum
@@ -45,85 +37,3 @@ def test_segment_sum_matrix_rows():
     data = jnp.arange(6.0).reshape(3, 2)
     out = segment_sum(data, jnp.array([1, 1, 0]), 2)
     np.testing.assert_array_equal(np.asarray(out), [[4.0, 5.0], [2.0, 4.0]])
-
-
-# ------------------------------------------------------------- segment_max
-def test_segment_max_empty_segment_holds_identity():
-    out = segment_max(jnp.array([3.0, 7.0]), jnp.array([0, 0]), 2)
-    assert float(out[0]) == 7.0
-    assert np.isneginf(float(out[1]))  # empty float segment -> -inf
-    out_i = segment_max(jnp.array([3, 7], dtype=jnp.int32), jnp.array([0, 0]), 2)
-    assert int(out_i[1]) == np.iinfo(np.int32).min
-
-
-# ------------------------------------------------------------ segment_mean
-def test_segment_mean_correct_means():
-    out = segment_mean(
-        jnp.array([2.0, 4.0, 9.0]), jnp.array([0, 0, 1]), 2
-    )
-    np.testing.assert_allclose(np.asarray(out), [3.0, 9.0])
-
-
-def test_segment_mean_empty_segment_is_exactly_zero():
-    """Regression: the old eps-division returned 0/eps noise for empty
-    segments (and slightly-off means everywhere else).  Empty must be
-    exactly 0.0, non-empty must be the exact mean."""
-    out = segment_mean(jnp.array([5.0, 7.0]), jnp.array([0, 0]), 3)
-    got = np.asarray(out)
-    assert got[0] == 6.0  # exact, not 12/(2+eps)
-    assert got[1] == 0.0 and got[2] == 0.0  # exact zero, no eps artifact
-    assert np.isfinite(got).all()
-
-
-def test_segment_mean_matrix_rows_empty_rows_zero():
-    data = jnp.array([[2.0, 4.0], [6.0, 8.0]])
-    out = segment_mean(data, jnp.array([2, 2]), 3)
-    np.testing.assert_array_equal(
-        np.asarray(out), [[0.0, 0.0], [0.0, 0.0], [4.0, 6.0]]
-    )
-
-
-# --------------------------------------------------------- segment_softmax
-def test_segment_softmax_normalizes_per_segment():
-    scores = jnp.array([1.0, 2.0, 3.0, 1.0])
-    ids = jnp.array([0, 0, 1, 1])
-    out = np.asarray(segment_softmax(scores, ids, 2))
-    assert out[0] + out[1] == pytest.approx(1.0)
-    assert out[2] + out[3] == pytest.approx(1.0)
-    assert out[1] > out[0] and out[2] > out[3]
-
-
-def test_segment_softmax_all_neg_inf_segment_is_finite():
-    """A segment whose scores are all -inf (fully-masked attention row)
-    must not produce NaN — the max-subtraction guard rewrites the -inf
-    segment max to 0 and the denominator is clamped."""
-    scores = jnp.array([-jnp.inf, -jnp.inf, 1.0, 2.0])
-    ids = jnp.array([0, 0, 1, 1])
-    out = np.asarray(segment_softmax(scores, ids, 2))
-    assert np.isfinite(out).all()
-    np.testing.assert_array_equal(out[:2], [0.0, 0.0])
-    assert out[2] + out[3] == pytest.approx(1.0)
-
-
-def test_segment_softmax_sentinel_rows_excluded_from_normalizer():
-    scores = jnp.array([0.0, 0.0, 100.0])
-    ids = jnp.array([0, 0, 5])  # third row is padding (>= num_segments)
-    out = np.asarray(segment_softmax(scores, ids, 2))
-    assert out[0] == pytest.approx(0.5) and out[1] == pytest.approx(0.5)
-
-
-# ------------------------------------------------------------ embedding_bag
-def test_embedding_bag_mean_empty_bag_is_zero_row():
-    table = jnp.arange(8.0).reshape(4, 2)
-    out = embedding_bag(
-        table, jnp.array([0, 1]), jnp.array([0, 0]), 2, mode="mean"
-    )
-    got = np.asarray(out)
-    np.testing.assert_array_equal(got[0], [1.0, 2.0])  # mean of rows 0,1
-    np.testing.assert_array_equal(got[1], [0.0, 0.0])  # empty bag -> zeros
-
-
-def test_embedding_bag_rejects_unknown_mode():
-    table = jnp.zeros((2, 2))
-    with pytest.raises(ValueError, match="unknown mode"):
-        embedding_bag(table, jnp.array([0]), jnp.array([0]), 1, mode="median")

@@ -166,6 +166,25 @@ class TestBudgetGridGeometry:
         for n, m in [(0, 0), (1, 0), (64, 128), (65, 128), (512, 2048),
                      (513, 1), (1, 5000), (300, 700)]:
             self._roundtrip(grid, n, m)
+        # a non-integer factor: the slot cell ceil(64 * 1.5**11) = 5536
+        # must round onto itself
+        self._roundtrip(BudgetGrid(min_nodes=1, min_slots=64, factor=1.5),
+                        0, 1846)
+
+    def test_default_factor_cells_are_power_of_two_multiples(self):
+        """At factor 2.0 each cell is the smallest ``lo * 2**k`` holding
+        the request: the budgets the default grid has always given."""
+        grid = BudgetGrid()
+
+        def want(x, lo):
+            return lo if x <= lo else lo << (-(-x // lo) - 1).bit_length()
+
+        sizes = list(range(0, 70000)) + [
+            (1 << j) + d for j in range(17, 30) for d in (-1, 0, 1)]
+        for x in sizes:
+            b = grid.budget_for(x, x)
+            assert b.n_budget == want(x, grid.min_nodes), x
+            assert b.slot_budget == want(2 * x, grid.min_slots), x
 
     @settings(max_examples=80, deadline=None)
     @given(
