@@ -1034,6 +1034,7 @@ class TriangleEngine:
         # all-gather, p in the ring; each horizontal edge once per device
         rounds = p if o.mode == "ring" else 1
         obs.incr("dist.counts")
+        obs.incr("dist.plans_profiled", int(_ptc._profiles_plan(o.mode)))
         obs.incr("dist.rows_planned", p * rounds * plan.probe_rows)
         obs.incr("dist.entries_planned", rounds * plan.gather_entries)
         obs.incr("dist.rows_real", p * int(nh))

@@ -26,13 +26,22 @@ counted exactly once (no /3 here — that dedup is the point of N-hat).
 
 All shapes are static; the two data-dependent capacities carry overflow
 flags (regular sampling bounds any receiver at 2x the average — the flags
-make the bound *checked* instead of assumed).  The intersection plan is
-likewise static: ``plan_hedge_rounds`` sizes its degree buckets, their
-target bands and its total rows on the host from the graph's degree
-histogram (an upper bound valid for any BFS), and ``run_plan``
-degree-sorts each gathered round in-trace so every query provably fits
-its bucket — width mis-fits, and more horizontal edges than a ring plan
-has rows, flag overflow instead of miscounting.
+make the bound *checked* instead of assumed).  ``run_plan`` sorts each
+horizontal block in-trace by the lengths of the sublists the device
+holds, so every query provably fits its bucket.
+
+In allgather mode the intersection plan is measured: a first program
+(``_tc_distributed_front``) runs through the gather and returns, per
+device, the sublist lengths of the gathered block (``_hedge_profile``);
+the host takes their maximum over devices and sizes the buckets, target
+bands and total rows from it (``plan_from_profile``); a second program
+(``_tc_distributed_probe``) probes under that plan, the data staying
+on the devices in between.  Ring mode, and the dry-run and comm
+lowerings, keep the one-program ``_tc_shard`` under the static plan of
+``plan_hedge_rounds``, sized on the host from the graph's degree
+histogram (an upper bound valid for any BFS).  Either way width
+mis-fits, and more horizontal edges than a plan has rows, flag overflow
+instead of miscounting.
 """
 from __future__ import annotations
 
@@ -58,7 +67,7 @@ from repro.core.intersect import (
     target_band_edges,
 )
 from repro.core.sampling import repartition_by_value
-from repro.graph.csr import Graph, _ceil_to, max_degree
+from repro.graph.csr import Graph, _ceil_to, _next_pow2, max_degree
 from repro.graph.partition import shard_edges
 
 
@@ -216,7 +225,62 @@ def plan_hedge_rounds(
     )
 
 
-def _tc_shard(
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class _Front:
+    """What one device holds between Algorithm 2's exchange and its
+    probe: the transposed sublists, the horizontal block it will probe
+    (the gathered one in allgather mode, its own in ring mode), its
+    partial counts and the replicated flags.  ``profile`` is the
+    per-device sublist-length table of the gathered block
+    (``_hedge_profile``), only where the plan is measured."""
+
+    owners: jnp.ndarray
+    values: jnp.ndarray
+    qu: jnp.ndarray
+    qw: jnp.ndarray
+    n_h: jnp.ndarray        # int32[1]: horizontal edges of this shard
+    m: jnp.ndarray          # int32[1]: undirected edges of this shard
+    recv: jnp.ndarray       # int32[1]: transposed elements received
+    sweeps: jnp.ndarray     # BFS sweeps (replicated)
+    transpose_overflow: jnp.ndarray
+    hedge_overflow: jnp.ndarray
+    profile: jnp.ndarray | None = None  # int32[1, K]
+
+
+def _front_specs(axis_name: str, profile: bool) -> _Front:
+    """``shard_map`` specs of a ``_Front``: per-device fields sharded
+    over ``axis_name``, the sweep count and the flags replicated."""
+    dev, rep = P(axis_name), P()
+    return _Front(
+        owners=dev, values=dev, qu=dev, qw=dev, n_h=dev, m=dev, recv=dev,
+        sweeps=rep, transpose_overflow=rep, hedge_overflow=rep,
+        profile=dev if profile else None,
+    )
+
+
+def _hedge_profile(adj, qu, qw, *, widths, edges):
+    """int32[1, len(widths) + len(edges) + 2]: this device's sublist
+    lengths over the block's real rows — per candidate width, the rows
+    whose smaller local list is longer; per band edge, the rows whose
+    larger local list is longer; the longest local list; the real rows.
+    Local lengths are what ``run_plan`` sorts the block by, so a plan
+    sized from the maximum over devices fits every device."""
+    n = adj.n_nodes
+    lu = adj.bounds(qu)[1]
+    lw = adj.bounds(qw)[1]
+    real = (qu < n) & (qw < n)
+    small = jnp.where(real, jnp.minimum(lu, lw), 0)
+    large = jnp.where(real, jnp.maximum(lu, lw), 0)
+    cols = (
+        [jnp.sum(small > w, dtype=jnp.int32) for w in widths]
+        + [jnp.sum(large > e, dtype=jnp.int32) for e in edges]
+        + [jnp.max(large), jnp.sum(real, dtype=jnp.int32)]
+    )
+    return jnp.stack(cols).astype(jnp.int32).reshape(1, -1)
+
+
+def _tc_front(
     src_i,
     dst_i,
     *,
@@ -225,22 +289,17 @@ def _tc_shard(
     root: int,
     cap_chunk: int,
     cap_hedge: int,
-    hplan: IntersectPlan,
+    limit: int,
     axis_name: str,
     mode: str = "allgather",
     frontier_dtype: str = "int32",
-    per_vertex: bool = False,
-):
-    """Per-device body. ``src_i/dst_i`` int32[cap_edges] sentinel-padded.
-
-    Besides the count, the result carries a ``CommTally``: per-phase
-    wire bytes of this very run, computed from the static capacities
-    plus the BFS sweep count (the one data-dependent factor — every
-    sweep is one frontier pmax).  ``tests/test_comm_instrument.py``
-    asserts the tally equals the per-collective volumes extracted from
-    the lowered program, so the collective inventory below cannot drift
-    from the accounting silently (see ``comm_model.NUM_SCALAR_REDUCES``
-    when adding or removing a scalar psum/pmax here)."""
+    profile: tuple | None = None,
+) -> _Front:
+    """Per-device first half of Algorithm 2, through its exchanges:
+    the BFS, N-hat, the transpose and the horizontal-edge block (lines
+    2-28 and the gather of 29-43).  ``limit`` is the most horizontal
+    edges a shard may hold before ``hedge_overflow``; ``profile``
+    (allgather mode) is the ``(widths, edges)`` of ``_hedge_profile``."""
     inf = n + 1
     # ---- line 2: parallel BFS + horizontal marking -------------------
     with jax.named_scope("bfs"):
@@ -262,39 +321,76 @@ def _tc_shard(
             axis_name=axis_name,
             inf=inf,
         )
-    # received pairs (owner v = carry, value x) sorted by (v, x) — exactly
-    # the engine's pair-list adjacency view; sublist(v) is a sorted slice
-    adj = PairListAdjacency(owners=rep.carry, values=rep.values, n_nodes=n)
 
-    # ---- lines 29-43: horizontal-edge exchange + planned intersections
+    # ---- lines 29-43: horizontal-edge exchange -----------------------
     is_h = horiz & (src_i < dst_i)
     with jax.named_scope("hedge"):
         order = jnp.argsort(~is_h, stable=True)
         hv = jnp.where(is_h[order], src_i[order], inf)[:cap_hedge]
         hw = jnp.where(is_h[order], dst_i[order], inf)[:cap_hedge]
         n_h_local = jnp.sum(is_h, dtype=jnp.int32)
-        # a ring round probes one device's block, and a plan that ends
-        # before it skips the rows past its end (``build_tc_shard_fn``
-        # refuses a plan too short for the gathered block: m rows)
-        limit = (cap_hedge if mode == "allgather"
-                 else min(cap_hedge, hplan.total_rows))
         hedge_overflow = (
             jax.lax.pmax((n_h_local > limit).astype(jnp.int32),
                          axis_name) > 0
         )
+        if mode == "allgather":
+            # one collective, volume k·m·p — identical to the paper's p
+            # rounds
+            hv = jax.lax.all_gather(hv, axis_name).reshape(-1)
+            hw = jax.lax.all_gather(hw, axis_name).reshape(-1)
+    prof = None
+    if profile is not None:
+        widths, edges = profile
+        adj = PairListAdjacency(owners=rep.carry, values=rep.values,
+                                n_nodes=n)
+        with jax.named_scope("profile"):
+            prof = _hedge_profile(adj, hv, hw, widths=widths, edges=edges)
+    # every BFS sweep ran one frontier pmax and assigned level cur+1 to
+    # at least one vertex (reseeds included), so sweeps = max level + 1;
+    # level is pmax-synced, hence replicated, hence so is the tally
+    sweeps = jnp.max(jnp.where(level == UNVISITED, 0, level)) + 1
+    return _Front(
+        owners=rep.carry, values=rep.values, qu=hv, qw=hw,
+        n_h=n_h_local.reshape(1),
+        m=jnp.sum(valid & (src_i < dst_i), dtype=jnp.int32).reshape(1),
+        recv=rep.count.reshape(1), sweeps=sweeps,
+        transpose_overflow=rep.overflow, hedge_overflow=hedge_overflow,
+        profile=prof,
+    )
 
+
+def _tc_back(
+    f: _Front,
+    *,
+    n: int,
+    p: int,
+    cap_chunk: int,
+    cap_hedge: int,
+    hplan: IntersectPlan,
+    axis_name: str,
+    mode: str = "allgather",
+    frontier_dtype: str = "int32",
+    per_vertex: bool = False,
+    guard_rows: bool = False,
+) -> ParallelTCResult:
+    """Per-device second half of Algorithm 2: the planned intersections
+    of the horizontal rounds and the reduction (lines 29-44).
+    ``guard_rows`` flags a gathered block with more real rows than the
+    plan probes (a measured plan ends at the rows it measured)."""
+    # received pairs (owner v = carry, value x) sorted by (v, x) — exactly
+    # the engine's pair-list adjacency view; sublist(v) is a sorted slice
+    adj = PairListAdjacency(owners=f.owners, values=f.values, n_nodes=n)
     # fori_loop carries must be device-varying from the start (shard_map vma)
     t0 = jax.lax.pvary(jnp.int32(0), (axis_name,))
     o0 = jax.lax.pvary(jnp.bool_(False), (axis_name,))
     if mode == "allgather":
-        # one collective, volume k·m·p — identical to the paper's p rounds
-        with jax.named_scope("hedge"):
-            all_hv = jax.lax.all_gather(hv, axis_name).reshape(-1)
-            all_hw = jax.lax.all_gather(hw, axis_name).reshape(-1)
         with jax.named_scope("probe"):
-            eng = run_plan(adj, all_hv, all_hw, hplan, per_vertex=per_vertex)
+            eng = run_plan(adj, f.qu, f.qw, hplan, per_vertex=per_vertex)
         t_i = t0 + eng.c1
         d_ovf = o0 | eng.overflow
+        if guard_rows:
+            real = jnp.sum((f.qu < n) & (f.qw < n), dtype=jnp.int32)
+            d_ovf = d_ovf | (real > hplan.total_rows)
         credit = eng.per_vertex
     elif mode == "ring":
         # probe the local shard, then p-1 ppermute rounds: O(cap_hedge)
@@ -305,7 +401,7 @@ def _tc_shard(
         # with allgather mode that the comm instrument asserts).
         perm = [(i, (i + 1) % p) for i in range(p)]
         with jax.named_scope("probe"):
-            eng0 = run_plan(adj, hv, hw, hplan, per_vertex=per_vertex)
+            eng0 = run_plan(adj, f.qu, f.qw, hplan, per_vertex=per_vertex)
 
         def round_body(r, carry):
             t, o, cv, cw = carry[:4]
@@ -319,7 +415,7 @@ def _tc_shard(
                 (carry[4] + eng.per_vertex,) if per_vertex else ()
             )
 
-        init = (t0 + eng0.c1, o0 | eng0.overflow, hv, hw) + (
+        init = (t0 + eng0.c1, o0 | eng0.overflow, f.qu, f.qw) + (
             (eng0.per_vertex,) if per_vertex else ()
         )
         res = jax.lax.fori_loop(0, p - 1, round_body, init)
@@ -340,17 +436,12 @@ def _tc_shard(
         pv = (
             jax.lax.psum(credit[:n], axis_name) if per_vertex else None
         )
-        n_h = jax.lax.psum(n_h_local, axis_name)
-        m = jax.lax.psum(jnp.sum(valid & (src_i < dst_i), dtype=jnp.int32),
-                         axis_name)
+        n_h = jax.lax.psum(f.n_h[0], axis_name)
+        m = jax.lax.psum(f.m[0], axis_name)
     k = n_h / jnp.maximum(m, 1)
-    # every BFS sweep ran one frontier pmax and assigned level cur+1 to
-    # at least one vertex (reseeds included), so sweeps = max level + 1;
-    # level is pmax-synced, hence replicated, hence so is the tally
-    sweeps = jnp.max(jnp.where(level == UNVISITED, 0, level)) + 1
     comm = tally_comm(
         n=n, p=p, cap_chunk=cap_chunk, cap_hedge=cap_hedge, mode=mode,
-        frontier_dtype=frontier_dtype, sweeps=sweeps,
+        frontier_dtype=frontier_dtype, sweeps=f.sweeps,
         per_vertex=per_vertex,
     )
     return ParallelTCResult(
@@ -358,11 +449,55 @@ def _tc_shard(
         per_device=t_i.reshape(1),
         k=k,
         num_horizontal=n_h,
-        transpose_overflow=rep.overflow | d_overflow,
-        hedge_overflow=hedge_overflow,
-        recv_counts=rep.count.reshape(1),
+        transpose_overflow=f.transpose_overflow | d_overflow,
+        hedge_overflow=f.hedge_overflow,
+        recv_counts=f.recv,
         comm=comm,
         per_vertex=pv,
+    )
+
+
+def _tc_shard(
+    src_i,
+    dst_i,
+    *,
+    n: int,
+    p: int,
+    root: int,
+    cap_chunk: int,
+    cap_hedge: int,
+    hplan: IntersectPlan,
+    axis_name: str,
+    mode: str = "allgather",
+    frontier_dtype: str = "int32",
+    per_vertex: bool = False,
+):
+    """Per-device body of Algorithm 2 as one program, under a plan fixed
+    before it runs: ``_tc_front`` then ``_tc_back``.  ``src_i/dst_i``
+    int32[cap_edges] sentinel-padded.
+
+    Besides the count, the result carries a ``CommTally``: per-phase
+    wire bytes of this very run, computed from the static capacities
+    plus the BFS sweep count (the one data-dependent factor — every
+    sweep is one frontier pmax).  ``tests/test_comm_instrument.py``
+    asserts the tally equals the per-collective volumes extracted from
+    the lowered program, so the collective inventory below cannot drift
+    from the accounting silently (see ``comm_model.NUM_SCALAR_REDUCES``
+    when adding or removing a scalar psum/pmax here)."""
+    # a ring round probes one device's block, and a plan that ends
+    # before it skips the rows past its end (``build_tc_shard_fn``
+    # refuses a plan too short for the gathered block: m rows)
+    limit = (cap_hedge if mode == "allgather"
+             else min(cap_hedge, hplan.total_rows))
+    f = _tc_front(
+        src_i, dst_i, n=n, p=p, root=root, cap_chunk=cap_chunk,
+        cap_hedge=cap_hedge, limit=limit, axis_name=axis_name, mode=mode,
+        frontier_dtype=frontier_dtype,
+    )
+    return _tc_back(
+        f, n=n, p=p, cap_chunk=cap_chunk, cap_hedge=cap_hedge, hplan=hplan,
+        axis_name=axis_name, mode=mode, frontier_dtype=frontier_dtype,
+        per_vertex=per_vertex,
     )
 
 
@@ -436,6 +571,100 @@ def _tc_distributed(src, dst, *, mesh: Mesh, body: tuple) -> ParallelTCResult:
     )(src, dst)
 
 
+@functools.partial(jax.jit, static_argnames=("mesh", "body"))
+def _tc_distributed_front(src, dst, *, mesh: Mesh, body: tuple) -> _Front:
+    """The first program of a measured count: ``_tc_front`` on every
+    device, with the sublist-length profile of the gathered block.
+    Keyed like ``_tc_distributed`` by the mesh and ``body``, the sorted
+    static keywords of ``_tc_front``."""
+    kw = dict(body)
+    axis = kw["axis_name"]
+    return jax.shard_map(
+        functools.partial(_tc_front, **kw),
+        mesh=mesh,
+        in_specs=(P(axis), P(axis)),
+        out_specs=_front_specs(axis, profile=kw["profile"] is not None),
+    )(src, dst)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "body"))
+def _tc_distributed_probe(f: _Front, *, mesh: Mesh,
+                          body: tuple) -> ParallelTCResult:
+    """The second program of a measured count: ``_tc_back`` on every
+    device over what ``_tc_distributed_front`` left there (``f``,
+    without its profile), under the plan in ``body``, the sorted static
+    keywords of ``_tc_back``."""
+    kw = dict(body)
+    axis = kw["axis_name"]
+    return jax.shard_map(
+        functools.partial(_tc_back, **kw),
+        mesh=mesh,
+        in_specs=(_front_specs(axis, profile=False),),
+        out_specs=result_out_specs(axis, per_vertex=kw["per_vertex"]),
+    )(f)
+
+
+def _profiles_plan(mode: str) -> bool:
+    """Whether a count in hedge ``mode`` plans its horizontal round from
+    the measured sublist lengths: allgather mode, whose one round is
+    known once the block is gathered.  Ring mode keeps the static
+    ``plan_hedge_rounds`` plan."""
+    return mode == "allgather"
+
+
+def plan_from_profile(
+    table,
+    *,
+    rows: int,
+    chunk: int,
+    widths: tuple[int, ...],
+    edges: tuple[int, ...],
+    d_max: int,
+    d_pad: int | None = None,
+    intersect_backend: str = "jnp",
+    interpret: bool = True,
+) -> IntersectPlan:
+    """The horizontal round's plan from the devices' measured profiles.
+
+    ``table`` is int32[p, K], one ``_hedge_profile`` row per device,
+    measured for the candidate ``widths`` and band ``edges``.  Its
+    maximum per column bounds every device, so one plan covers them
+    all: candidate buckets and target bands sized by the measured
+    counts (``plan_buckets_bounded``), the top width the longest local
+    list, the plan's end the block's real rows rounded to ``chunk``.
+    The top width (no wider than ``d_max``, the graph's largest degree,
+    or ``d_pad``, where the caller set one) and each bucket and band
+    bound round up to a power of two, the bounds in ``chunk`` rows:
+    another numbering of one graph splits its lists over the devices
+    otherwise, and would compile a new probe program for a plan a few
+    chunks apart.  A bound that does not hold flags overflow instead
+    of miscounting."""
+    import numpy as np
+
+    bound = np.asarray(table).max(axis=0)
+    nw, ne = len(widths), len(edges)
+    small, large = bound[:nw], bound[nw:nw + ne]
+    longest, real = int(bound[nw + ne]), int(bound[nw + ne + 1])
+    end = _ceil_to(real, chunk)
+
+    def rounded(b):  # whole chunks, a power of two of them, within end
+        return min(chunk * _next_pow2(-(-int(b) // chunk)), end) if b else 0
+
+    top = (int(d_pad) if d_pad is not None
+           else min(_next_pow2(max(1, longest)), int(d_max)))
+    return plan_buckets_bounded(
+        rows,
+        d_pad=top,
+        exceed=tuple(zip(widths, map(rounded, small))),
+        exceed_large=((0, real),) + tuple(zip(edges, map(rounded, large))),
+        bucket_widths=widths,
+        row_mult=chunk,
+        backend=intersect_backend,
+        interpret=interpret,
+        query_chunk=chunk,
+    )
+
+
 def _parallel_triangle_count(
     g: Graph, mesh: Mesh, *, axis_name: str = "p", options
 ) -> tuple[ParallelTCResult, IntersectPlan]:
@@ -443,7 +672,14 @@ def _parallel_triangle_count(
     ``mode`` already resolved to ``"allgather"`` or ``"ring"`` (the
     ``"auto"`` hedge-mode policy lives in the engine,
     ``TriangleEngine.count_distributed_raw``).  Returns the result and
-    the horizontal-round plan every device ran."""
+    the horizontal-round plan every device ran.
+
+    Allgather mode runs two programs: ``_tc_distributed_front`` up to
+    the gathered block and its per-device profile, which the host
+    fetches (``tc.plan_sync``) and plans from (``plan_from_profile``,
+    ``tc.plan_layout``), then ``_tc_distributed_probe`` under that
+    plan; the data stays on the devices in between.  Ring mode runs the
+    one program ``_tc_distributed`` under the static plan."""
     o = options
     if o.mode not in ("allgather", "ring"):
         raise ValueError(
@@ -455,19 +691,45 @@ def _parallel_triangle_count(
     frontier_dtype, d_pad = o.frontier_dtype, o.d_pad
     p = mesh.shape[axis_name]
     m2 = int(jax.device_get(g.n_edges_dir))
-    if d_pad is None:
-        d_pad = max(1, max_degree(g))
+    d_top = d_pad if d_pad is not None else max(1, max_degree(g))
     # shard once: the same host-side pass feeds the shard_map inputs AND
     # the ring plan's per-shard degree bounds
-    cap_edges = _capacities(m2, p, slack)[0]
+    cap_edges, cap_chunk, cap_hedge = _capacities(m2, p, slack)
     sharding = NamedSharding(mesh, P(axis_name))
     with obs.span("tc.shard"):
         s_sh, d_sh, _, _ = shard_edges(g, p, capacity=cap_edges)
         s_dev = jax.device_put(s_sh.reshape(-1), sharding)
         d_dev = jax.device_put(d_sh.reshape(-1), sharding)
+    if _profiles_plan(mode):
+        rows, chunk = _hedge_layout(m2, p, mode, hedge_chunk)
+        widths = tuple(sorted(
+            w for w in {int(w) for w in bucket_widths} if 0 < w < d_top
+        ))
+        edges = target_band_edges(d_top)
+        common = dict(n=g.n_nodes, p=p, cap_chunk=cap_chunk,
+                      cap_hedge=cap_hedge, axis_name=axis_name, mode=mode,
+                      frontier_dtype=frontier_dtype)
+        f = _tc_distributed_front(s_dev, d_dev, mesh=mesh, body=tuple(sorted(
+            dict(common, root=root, limit=cap_hedge,
+                 profile=(widths, edges)).items())))
+        with obs.span("tc.plan_sync"):
+            table = jax.device_get(f.profile)
+        with obs.span("tc.plan_layout"):
+            hplan = plan_from_profile(
+                table, rows=rows, chunk=chunk, widths=widths, edges=edges,
+                d_max=d_top, d_pad=d_pad, intersect_backend=backend,
+                interpret=interpret,
+            )
+        with obs.span("tc.probe"):
+            res = _tc_distributed_probe(
+                dataclasses.replace(f, profile=None), mesh=mesh,
+                body=tuple(sorted(dict(
+                    common, hplan=hplan, per_vertex=bool(o.per_vertex),
+                    guard_rows=True).items())))
+        return res, hplan
     with obs.span("tc.plan_layout"):
         hplan = plan_hedge_rounds(
-            g, p, mode=mode, hedge_chunk=hedge_chunk, d_pad=d_pad,
+            g, p, mode=mode, hedge_chunk=hedge_chunk, d_pad=d_top,
             bucket_widths=bucket_widths, intersect_backend=backend,
             interpret=interpret, shards=(s_sh, d_sh),
         )
@@ -477,7 +739,7 @@ def _parallel_triangle_count(
     # ignore the caller's choice — plumb all three
     fn, _ = build_tc_shard_fn(
         n=g.n_nodes, m2=m2, p=p, axis_name=axis_name, root=root, slack=slack,
-        d_pad=d_pad, mode=mode, hedge_chunk=hedge_chunk, hplan=hplan,
+        d_pad=d_top, mode=mode, hedge_chunk=hedge_chunk, hplan=hplan,
         intersect_backend=backend, interpret=interpret,
         frontier_dtype=frontier_dtype, per_vertex=bool(o.per_vertex),
     )

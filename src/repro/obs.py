@@ -11,9 +11,12 @@
   ``tc.fetch`` (the result ``device_get``); the server records
   ``serve.flush`` and ``serve.finalize``.  The distributed route
   (Algorithm 2) records ``tc.ingest``, ``tc.shard`` (``shard_edges``
-  and the uploads of the shards), ``tc.plan_layout``
-  (``plan_hedge_rounds``), ``tc.probe`` (the dispatch of
-  ``_tc_distributed``) and ``tc.fetch``.
+  and the uploads of the shards), in allgather mode ``tc.plan_sync``
+  (the ``device_get`` of the per-device profile that
+  ``_tc_distributed_front`` measured), ``tc.plan_layout``
+  (``plan_from_profile``; ``plan_hedge_rounds`` in ring mode),
+  ``tc.probe`` (the dispatch of ``_tc_distributed_probe``; of
+  ``_tc_distributed`` in ring mode) and ``tc.fetch``.
 * A process-wide counter registry: :func:`incr` adds host-side numbers
   the caller already holds, :func:`counters` is a snapshot, and
   :func:`reset` zeroes it.  The counters of the local route, both added
@@ -31,6 +34,9 @@
 
   ``dist.counts``
       distributed counts made;
+  ``dist.plans_profiled``
+      those whose horizontal-round plan was measured from the gathered
+      block's sublist lengths (allgather mode), not the static plan;
   ``dist.rows_planned``
       rows the devices probed: p × the horizontal-round plan's rows,
       p times that again in ring mode (p rounds);

@@ -2,8 +2,10 @@
 ``TriangleEngine(mesh=<1-D mesh "p">).count(..., route="distributed")``
 equals the brute-force oracle and the local route, its per-device
 partials sum to its total, it flags no overflow, a second count of the
-same graph traces nothing, and the ``dist.*`` counters hold what the
-plan and the report give.  The spans of the route nest inside
+same graph traces neither of its two programs, and the ``dist.*``
+counters hold what the plan that ran and the report give.  The plan
+measured from the gathered block is never larger than the static one
+from the whole graph's degrees.  The spans of the route nest inside
 ``tc.count`` in a real profiler trace (one device, in process)."""
 from __future__ import annotations
 
@@ -24,8 +26,8 @@ sys.path.insert(0, str(ROOT))
 from bench import tracing  # noqa: E402
 
 GRAPHS = ("kron9", "urand", "components")
-DIST_SPANS = ("tc.ingest", "tc.shard", "tc.plan_layout", "tc.probe",
-              "tc.fetch")
+DIST_SPANS = ("tc.ingest", "tc.shard", "tc.plan_sync", "tc.plan_layout",
+              "tc.probe", "tc.fetch")
 
 BODY = """
 import json
@@ -38,12 +40,25 @@ from repro.graph import generators as gen
 from repro.graph.csr import from_edges
 from tests import oracle
 
-traces = []
-body = ptc._tc_shard
-def counted(*a, **kw):
-    traces.append(1)
-    return body(*a, **kw)
-ptc._tc_shard = counted
+traces = {"front": [], "back": []}
+def hooked(name, body):
+    def counted(*a, **kw):
+        traces[name].append(1)
+        return body(*a, **kw)
+    return counted
+ptc._tc_front = hooked("front", ptc._tc_front)
+ptc._tc_back = hooked("back", ptc._tc_back)
+
+plans = []  # the plan each count's report was given
+impl = ptc._parallel_triangle_count
+def recorded(*a, **kw):
+    res, plan = impl(*a, **kw)
+    plans.append(plan)
+    return res, plan
+ptc._parallel_triangle_count = recorded
+
+def ntraces():
+    return {k: len(v) for k, v in traces.items()}
 
 def components():
     # two cliques, a ring of cliques, a sparse R-MAT piece and isolated
@@ -65,22 +80,27 @@ engine = TriangleEngine(mesh=mesh)
 out = {}
 for name, (edges, n) in graphs.items():
     obs.reset()
-    t0 = len(traces)
+    t0 = ntraces()
     rep = engine.count((edges, n), route="distributed")
-    t1 = len(traces)
+    t1 = ntraces()
     again = engine.count((edges, n), route="distributed")
+    t2 = ntraces()
     c = obs.counters()
     g = from_edges(edges, n)
-    plan = ptc.plan_hedge_rounds(g, 4)
+    plan = plans[-1]
+    assert plans[-2] == plan
+    static = ptc.plan_hedge_rounds(g, 4)
     out[name] = {
         "oracle": int(oracle.triangle_counts(edges, n).sum()) // 3,
         "local": engine.count((edges, n), route="local").triangles,
         "dist": rep.triangles, "again": again.triangles,
         "per_device": [int(x) for x in rep.per_device],
         "overflow": bool(rep.overflow), "plan_id": rep.plan_id,
-        "traces_first": t1 - t0, "traces_second": len(traces) - t1,
+        "traces_first": {k: t1[k] - t0[k] for k in t0},
+        "traces_second": {k: t2[k] - t1[k] for k in t0},
         "counters": c, "plan_rows": plan.probe_rows,
         "plan_entries": plan.gather_entries,
+        "static_entries": static.gather_entries,
         "m": int(g.n_edges_dir) // 2, "plan_chunk": plan.query_chunk,
         "num_horizontal": rep.num_horizontal, "wire": rep.comm.total,
     }
@@ -95,6 +115,8 @@ out["ring"] = {
                                        mode="ring").probe_rows,
     "plan_entries": ptc.plan_hedge_rounds(from_edges(edges, n), 4,
                                           mode="ring").gather_entries,
+    "ran_static": plans[-1] == ptc.plan_hedge_rounds(from_edges(edges, n),
+                                                     4, mode="ring"),
 }
 print("RESULT", json.dumps(out))
 """
@@ -125,8 +147,8 @@ def test_per_device_partials_sum_to_total_without_overflow(counts, name):
 
 @pytest.mark.parametrize("name", GRAPHS)
 def test_second_count_of_a_graph_traces_nothing(counts, name):
-    assert counts[name]["traces_first"] == 1
-    assert counts[name]["traces_second"] == 0
+    assert counts[name]["traces_first"] == {"front": 1, "back": 1}
+    assert counts[name]["traces_second"] == {"front": 0, "back": 0}
 
 
 @pytest.mark.parametrize("name", GRAPHS)
@@ -134,20 +156,29 @@ def test_dist_counters_hold_plan_and_report(counts, name):
     c = counts[name]
     assert c["counters"] == {
         "dist.counts": 2,
+        "dist.plans_profiled": 2,
         "dist.rows_planned": 2 * 4 * c["plan_rows"],
         "dist.rows_real": 2 * 4 * c["num_horizontal"],
         "dist.entries_planned": 2 * c["plan_entries"],
         "dist.wire_bytes": 2 * c["wire"],
     }
-    # the plan ends at the first chunk past the m rows that can be real
-    assert 0 < c["num_horizontal"] <= c["m"] <= c["plan_rows"]
-    assert c["plan_rows"] < c["m"] + c["plan_chunk"]
+    # the measured plan ends at the first chunk past the real rows
+    assert 0 < c["num_horizontal"] <= c["plan_rows"]
+    assert c["plan_rows"] < c["num_horizontal"] + c["plan_chunk"]
+
+
+@pytest.mark.parametrize("name", GRAPHS)
+def test_measured_plan_gathers_no_more_than_static(counts, name):
+    c = counts[name]
+    assert 0 < c["plan_entries"] <= c["static_entries"]
 
 
 def test_ring_mode_counts_p_rounds_of_rows(counts):
     r = counts["ring"]
     assert r["dist"] == counts["kron9"]["oracle"]
     assert r["plan_id"] == "hedge/ring/p4"
+    assert r["counters"]["dist.plans_profiled"] == 0
+    assert r["ran_static"]
     assert r["counters"]["dist.rows_planned"] == 4 * 4 * r["plan_rows"]
     assert r["counters"]["dist.rows_real"] == 4 * r["num_horizontal"]
     assert r["counters"]["dist.entries_planned"] == 4 * r["plan_entries"]

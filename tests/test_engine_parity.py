@@ -411,6 +411,43 @@ def test_hedge_plan_ends_at_the_rows_that_can_be_real(name, mode):
                                           mode="allgather", hedge_chunk=64)
 
 
+@pytest.mark.parametrize("backend", ("jnp", "pallas"))
+def test_profile_plan_rounds_to_share_compiles(backend):
+    """Two measured profiles of one graph whose bounds fall in the same
+    power-of-two cells (of 1024-row chunks; the longest list of
+    widths) give one plan, so a numbering of the graph that splits its
+    lists otherwise reuses the compiled probe; every bound still covers
+    its count, the top width its longest list, and the plan ends at
+    the real rows."""
+    from repro.core import parallel_tc
+
+    kw = dict(rows=882_192, chunk=1024, widths=(32, 256),
+              edges=(512, 2048), d_max=5993, intersect_backend=backend)
+    # per device: rows past width 32, 256; past edge 512, 2048; the
+    # longest local list; the real rows
+    a = np.array([[68_240, 367, 18_879, 43, 2_487, 339_710],
+                  [45_020, 48, 9_000, 0, 1_006, 339_710]])
+    b = np.array([[87_151, 810, 28_327, 43, 2_841, 339_710],
+                  [30_201, 18, 2_368, 0, 1_035, 339_710]])
+    plan = parallel_tc.plan_from_profile(a, **kw)
+    assert plan == parallel_tc.plan_from_profile(b, **kw)
+    assert plan.total_rows == 339_968  # ceil(339,710, 1024)
+    assert max(bk.d_targ for bk in plan.buckets) == 4096
+    # rows planned at least as wide as each bound, widest first
+    ranked = sorted(plan.buckets, key=lambda bk: bk.start)
+    for w, need in ((32, 87_151), (256, 810)):
+        wide = sum(bk.rows for bk in ranked if bk.d_cand > w)
+        assert wide >= need
+    if backend == "pallas":
+        for e, need in ((512, 28_327), (2048, 43)):
+            assert sum(bk.rows for bk in ranked if bk.d_targ > e) >= need
+    # the top width stops at the largest degree
+    c = a.copy()
+    c[0, 4] = 5_000
+    top = parallel_tc.plan_from_profile(c, **kw)
+    assert max(bk.d_targ for bk in top.buckets) == 5993
+
+
 def _hub_er():
     """``conftest.hub_graph`` (a 600-leaf hub on a 10-clique) beside a
     uniform piece: larger lists on both sides of the first band edge."""
@@ -441,6 +478,29 @@ from tests.test_engine_parity import _BAND_GRAPHS
 
 mesh = Mesh(np.array(jax.devices()[:4]), ("p",))
 engine = TriangleEngine(mesh=mesh)
+ran, tables = [], []  # the plan of each count, each measured profile
+impl, planner = ptc._parallel_triangle_count, ptc.plan_from_profile
+def recorded(*a, **kw):
+    res, plan = impl(*a, **kw)
+    ran.append(plan)
+    return res, plan
+def measured(table, **kw):
+    tables.append((np.asarray(table), kw))
+    return planner(table, **kw)
+ptc._parallel_triangle_count = recorded
+ptc.plan_from_profile = measured
+
+def cut_rows(table, **kw):  # the real rows' bound a chunk short
+    tables.append((np.asarray(table), kw))
+    table = np.array(table)
+    table[:, -1] -= kw["chunk"]
+    return planner(table, **kw)
+def cut_width(table, **kw):  # the first candidate width's bound to 0
+    tables.append((np.asarray(table), kw))
+    table = np.array(table)
+    table[:, 0] = 0
+    return planner(table, **kw)
+
 out = {}
 for name in ("hub", "er"):
     edges, n = _BAND_GRAPHS[name]()
@@ -455,11 +515,42 @@ for name in ("hub", "er"):
                 plan = ptc.plan_hedge_rounds(
                     from_edges(edges, n), 4, mode=mode, hedge_chunk=64,
                     intersect_backend=backend)
-                out[f"{name}/{root}/{mode}/{backend}"] = {
+                case = f"{name}/{root}/{mode}/{backend}"
+                out[case] = {
                     "want": want, "got": rep.triangles,
                     "overflow": bool(rep.overflow),
                     "banded": bool(plan.band_ends),
+                    "ran_entries": ran[-1].gather_entries,
+                    "static_entries": plan.gather_entries,
                 }
+                if mode == "ring":
+                    continue
+                # plans from a profile with one bound or the width cut
+                # short: the real rows' bound, a candidate width's bound
+                # (at width 1, which real rows exceed), the top width
+                longest = int(tables[-1][0][:, -2].max())
+                cuts = {
+                    "rows": (cut_rows, o),
+                    "width_bound": (cut_width, TCOptions(
+                        backend=backend, interpret=True, root=root,
+                        mode=mode, hedge_chunk=64, bucket_widths=(1,))),
+                    "top": (measured, TCOptions(
+                        backend=backend, interpret=True, root=root,
+                        mode=mode, hedge_chunk=64, d_pad=longest - 1)),
+                }
+                for cut, (fn, oc) in cuts.items():
+                    ptc.plan_from_profile = fn
+                    try:
+                        r = engine.count((edges, n), route="distributed",
+                                         options=oc)
+                    finally:
+                        ptc.plan_from_profile = measured
+                    out[f"{case}/{cut}"] = {
+                        "want": want, "got": r.triangles,
+                        "overflow": bool(r.overflow),
+                        "width_rows": int(tables[-1][0][:, 0].max())
+                        if cut == "width_bound" else None,
+                    }
 # a ring plan one chunk past the least the guard lets through: the
 # complete graph's first shard holds more horizontal edges than that
 edges, n = _BAND_GRAPHS["complete"]()
@@ -504,6 +595,31 @@ def test_banded_algorithm2_equals_oracle(banded_runs, case):
     name, _, mode, backend = case.split("/")
     if backend == "jnp" or name == "hub" and mode == "allgather":
         assert r["banded"] == (backend == "pallas")
+
+
+MEASURED_CASES = [c for c in BANDED_CASES if "/allgather/" in c]
+
+
+@pytest.mark.parametrize("case", MEASURED_CASES)
+def test_measured_plan_gathers_no_more_than_static(banded_runs, case):
+    """In allgather mode the plan that ran is measured from the
+    gathered block's sublist lengths on each device: never more list
+    entries than the static plan from the whole graph's degrees."""
+    r = banded_runs[case]
+    assert 0 < r["ran_entries"] <= r["static_entries"], r
+
+
+@pytest.mark.parametrize("cut", ("rows", "width_bound", "top"))
+@pytest.mark.parametrize("case", MEASURED_CASES)
+def test_cut_profile_flags_overflow(banded_runs, case, cut):
+    """A plan from a measured profile with one bound cut short (the
+    real rows a chunk short, the rows past width 1 set to 0) or with
+    its top width below the longest local list flags overflow; the
+    flagged count never exceeds the truth."""
+    r = banded_runs[f"{case}/{cut}"]
+    if cut == "width_bound":
+        assert r["width_rows"] > 0
+    assert r["overflow"] and r["got"] <= r["want"], r
 
 
 def test_too_short_ring_plan_flags_hedge_overflow(banded_runs):
